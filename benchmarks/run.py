@@ -1132,6 +1132,8 @@ def main() -> None:
     # must land before the lazy `import jax` inside the bench fns
     from repro.flags import force_host_device_count
     force_host_device_count(args.tp)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     if args.only in EXTRA_BENCHES:
@@ -1154,12 +1156,17 @@ def main() -> None:
     fns = PT.ALL
     if args.only:
         fns = [f for f in PT.ALL if f.__name__ == args.only]
+    failed = []
     for fn in fns:
         try:
             fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report every table, then fail
             from benchmarks.common import emit
             emit(fn.__name__, 0.0, f"ERROR {type(e).__name__}: {e}")
+            failed.append(fn.__name__)
+    if failed:
+        raise SystemExit(f"{len(failed)} bench function(s) failed: "
+                         f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -373,7 +373,9 @@ def prefix_tune(api, params, cushion0: Params,
                 grad_clip=1.0, frozen=frozen)
     state = opt.init(cushion0)
 
-    def loss(cush, batch):
+    # the frozen params are an argument of the step, never a closure: a
+    # closed-over model would be baked into the executable as constants
+    def loss(cush, batch, params):
         cush = stop_grad_frozen(cush)
         _, aux = api.loss_fn(params, batch, qcfg, scales=scales,
                              cushion=cush, collect=True, remat=False)
@@ -382,8 +384,9 @@ def prefix_tune(api, params, cushion0: Params,
         return total, {"ce": aux["ce"], "range": reg,
                        "qerr": aux.get("qerr", jnp.zeros(()))}
 
-    def step(cush, state, batch):
-        (l, aux), g = jax.value_and_grad(loss, has_aux=True)(cush, batch)
+    def step(cush, state, batch, params):
+        (l, aux), g = jax.value_and_grad(loss, has_aux=True)(cush, batch,
+                                                             params)
         cush, state, om = opt.update(g, state, cush)
         return cush, state, {"loss": l, **aux, "gnorm": om["grad_norm"]}
 
@@ -405,7 +408,8 @@ def prefix_tune(api, params, cushion0: Params,
         c_sh = replicated_shardings(cushion0, mesh)
         o_sh = replicated_shardings(jax.eval_shape(opt.init, cushion0),
                                     mesh)
-        step_fn = shard_update_step(step, mesh, c_sh, o_sh, first)
+        step_fn = shard_update_step(step, mesh, c_sh, o_sh, first,
+                                    n_extra=1)
         cushion = jax.device_put(cushion, c_sh)
         state = jax.device_put(state, o_sh)
 
@@ -431,7 +435,7 @@ def prefix_tune(api, params, cushion0: Params,
     for i, batch in enumerate(itertools.chain([first], it)):
         if i >= ccfg.tune_steps:
             break
-        cushion, state, m = step_fn(cushion, state, batch)
+        cushion, state, m = step_fn(cushion, state, batch, params)
         pending.append((i, m))
         if len(pending) >= log_every:
             drain()

@@ -249,12 +249,65 @@ def _use_w4a8_kernel() -> bool:
 
 
 def _tile(n: int, target: int) -> int:
-    """Largest power-of-two block <= target that divides n (weight dims are
-    static per checkpoint; falls to 1 only for pathological odd dims)."""
-    t = min(target, n)
-    while n % t:
+    """Block for a static weight dim: the largest power-of-two multiple of
+    128 <= target that divides n, else n itself (a full-dim block is always
+    legal for the TPU compiler; a 64-wide one is not)."""
+    t = target
+    while t >= 128:
+        if n % t == 0:
+            return t
         t //= 2
-    return max(t, 1)
+    return n
+
+
+def _tp_mesh():
+    """The serving tensor-parallel mesh active around this trace, if its
+    ``tp`` axis is wider than one device. Pallas kernels cannot be
+    partitioned by the compiler, so the kernel routes shard_map themselves
+    over it."""
+    from repro.distributed.sharding import active_mesh
+    mesh = active_mesh()
+    if mesh is None or "tp" not in mesh.axis_names or mesh.shape["tp"] == 1:
+        return None
+    return mesh
+
+
+def _tp_kernel(mesh, fn, x, w, cols, rest, row_parallel: bool,
+               row_partial=None, rows=()):
+    """Run the 2-D matmul kernel ``fn(x, w, *cols, *rest)`` per tp shard.
+
+    Column-parallel weights (output dim sharded: the serve rules for every
+    weight that reads the residual stream) give each shard its output
+    columns; ``cols`` are per-output-column operands sharded alongside.
+    Row-parallel weights (contraction dim sharded: ``wo``, ``w_down``, ...)
+    run ``row_partial(x, w, *rows, *rest)`` per shard, with ``rows`` split
+    along their first dim like the weight, and psum the partials.
+    ``rest`` is replicated. A dim that does not divide the mesh falls back
+    to one replicated call per device (still ``row_partial`` for a
+    row-parallel weight, so the caller's epilogue is the same)."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.distributed.sharding import shard_map
+    tp = mesh.shape["tp"]
+    rep = tuple(P() for _ in rest)
+    if row_parallel:
+        args = (x, w) + tuple(rows) + tuple(rest)
+        if all(a.shape[0] % tp == 0 for a in (x.T, w) + tuple(rows)):
+            return shard_map(
+                lambda *a: jax.lax.psum(row_partial(*a), "tp"), mesh,
+                in_specs=(P(None, "tp"), P("tp", None))
+                + tuple(P("tp", None) for _ in rows) + rep,
+                out_specs=P())(*args)
+        return shard_map(row_partial, mesh, in_specs=(P(),) * len(args),
+                         out_specs=P())(*args)
+    args = (x, w) + tuple(cols) + tuple(rest)
+    if w.shape[1] % tp == 0:
+        col_specs = tuple(P(*([None] * (c.ndim - 1)), "tp") for c in cols)
+        return shard_map(fn, mesh,
+                         in_specs=(P(), P(None, "tp")) + col_specs + rep,
+                         out_specs=P(None, "tp"))(*args)
+    return shard_map(fn, mesh, in_specs=(P(),) * len(args),
+                     out_specs=P())(*args)
 
 
 _F32_EXACT_K = 1024  # 1024 * 128 * 128 == 2**24: f32 partial sums stay exact
@@ -287,7 +340,8 @@ def _int_product_f32_exact(xq: Array, w_int: Array) -> Array:
 
 
 def _int8_matmul(xq: Array, w_int: Array, s_x, z_x, s_w,
-                 colsum: Array, out_dtype) -> Array:
+                 colsum: Array, out_dtype, row_parallel: bool = False
+                 ) -> Array:
     """Shared int8 x int8 epilogue-fused matmul behind ``true_int_dot`` and
     ``prequantized_int_dot``:
 
@@ -300,19 +354,38 @@ def _int8_matmul(xq: Array, w_int: Array, s_x, z_x, s_w,
     int8 MXU tiles with the scalar dequant fused in the kernel epilogue and
     ragged M padded/sliced inside the kernel wrapper — so every 2-D
     ``qlinear`` site (prefill *and* the jitted decode scan) hits the
-    MXU-int8 fast path. Scalar (per-tensor static) scales only."""
+    MXU-int8 fast path. Scalar (per-tensor static) scales only.
+
+    Under a tp mesh the kernel is shard_mapped (``_tp_kernel``):
+    ``row_parallel`` says the weight's contraction dim is the sharded one.
+    Row-parallel shards return raw int32 partials, psum them exactly, and
+    apply the epilogue once — the same values as the unsharded kernel."""
     if _use_w8a8_kernel() and w_int.ndim == 2 and jnp.ndim(s_x) == 0:
         from repro.kernels.w8a8_matmul import w8a8_matmul
-        K, N = w_int.shape
+        interpret = jax.default_backend() != "tpu"
         lead = xq.shape[:-1]
-        M = 1
-        for d in lead:
-            M *= d
-        out = w8a8_matmul(
-            xq.reshape(M, K), w_int, s_x, z_x, s_w, colsum=colsum,
-            bm=256, bn=_tile(N, 512), bk=_tile(K, 256),
-            interpret=jax.default_backend() != "tpu")
-        return out.reshape(*lead, N).astype(out_dtype)
+        x2 = xq.reshape(-1, xq.shape[-1])
+
+        def run(x, w, cs, s_x, z_x, s_w, epilogue=True):
+            K, N = w.shape
+            return w8a8_matmul(x, w, s_x, z_x, s_w, colsum=cs, bm=256,
+                               bn=_tile(N, 512), bk=_tile(K, 256),
+                               epilogue=epilogue, interpret=interpret)
+
+        scalars = tuple(jnp.asarray(v, jnp.float32) for v in (s_x, z_x, s_w))
+        mesh = _tp_mesh()
+        if mesh is None:
+            out = run(x2, w_int, colsum, *scalars)
+        elif row_parallel:
+            acc = _tp_kernel(
+                mesh, run, x2, w_int, (colsum,), scalars, True,
+                row_partial=lambda x, w, *sc: run(x, w, None, *sc,
+                                                  epilogue=False))
+            out = (acc.astype(jnp.float32) - scalars[1]
+                   * colsum.astype(jnp.float32)) * (scalars[0] * scalars[2])
+        else:
+            out = _tp_kernel(mesh, run, x2, w_int, (colsum,), scalars, False)
+        return out.reshape(*lead, -1).astype(out_dtype)
     if jax.default_backend() != "tpu":
         acc = _int_product_f32_exact(xq, w_int)
     else:
@@ -326,7 +399,8 @@ def _int8_matmul(xq: Array, w_int: Array, s_x, z_x, s_w,
 
 
 def _int4_matmul(xq: Array, w_packed: Array, s_x, z_x, s_w,
-                 colsum: Array, out_dtype) -> Array:
+                 colsum: Array, out_dtype, row_parallel: bool = False
+                 ) -> Array:
     """int8 activations x int4-packed weights with group-wise weight scales:
 
       out = s_x * ( sum_g s_w[g,:] * (X_int[:, g] @ W_int[g, :])
@@ -347,6 +421,9 @@ def _int4_matmul(xq: Array, w_packed: Array, s_x, z_x, s_w,
     exactness for one extra f32 rounding per weight element (~1e-7
     relative); the kernel accumulates per-group like the grouped form, and
     the two routes agree to f32-accumulation tolerance, not bit-identically.
+    Under a tp mesh the kernel is shard_mapped like ``_int8_matmul``;
+    row-parallel shards psum f32 partials (group scales apply per shard),
+    so tp and unsharded results agree to f32 rounding, not bit for bit.
     """
     K = xq.shape[-1]
     G = s_w.shape[0]
@@ -356,13 +433,34 @@ def _int4_matmul(xq: Array, w_packed: Array, s_x, z_x, s_w,
     lead = xq.shape[:-1]
     if _use_w4a8_kernel() and w_packed.ndim == 2 and jnp.ndim(s_x) == 0:
         from repro.kernels.w4a8_matmul import w4a8_matmul
-        M = 1
-        for d in lead:
-            M *= d
-        out = w4a8_matmul(
-            xq.reshape(M, K), w_packed, s_x, z_x, s_w, colsum,
-            group_size=group, bm=256, bn=_tile(N, 512),
-            interpret=jax.default_backend() != "tpu")
+        interpret = jax.default_backend() != "tpu"
+        x2 = xq.reshape(-1, K)
+
+        def run(x, w, sw, cs, s_x, z_x):
+            return w4a8_matmul(x, w, s_x, z_x, sw, cs, group_size=group,
+                               bm=256, bn=_tile(w.shape[1], 512),
+                               interpret=interpret)
+
+        scalars = (jnp.asarray(s_x, jnp.float32),
+                   jnp.asarray(z_x, jnp.float32))
+        mesh = _tp_mesh()
+        if mesh is None:
+            out = run(x2, w_packed, s_w, colsum, *scalars)
+        elif row_parallel:
+            # shards sum their groups' s_w[g] * (x_g @ w_g) (unit s_x, zero
+            # z_x, zero colsum); the zero-point correction and s_x apply once
+            # after the psum
+            acc = _tp_kernel(
+                mesh, run, x2, w_packed, (s_w, colsum), scalars, True,
+                row_partial=lambda x, w, sw, *_: run(
+                    x, w, sw, jnp.zeros((w.shape[1],), jnp.float32),
+                    jnp.float32(1), jnp.float32(0)),
+                rows=(s_w,))
+            out = (acc - scalars[1] * colsum.astype(jnp.float32)) \
+                * scalars[0]
+        else:
+            out = _tp_kernel(mesh, run, x2, w_packed, (s_w, colsum),
+                             scalars, False)
         return out.reshape(*lead, N).astype(out_dtype)
     wq = unpack_int4(w_packed, K)                          # (K, N) int8
     wdq = wq.astype(jnp.float32).reshape(G, group, N) \
@@ -374,7 +472,8 @@ def _int4_matmul(xq: Array, w_packed: Array, s_x, z_x, s_w,
 
 
 def true_int_dot(x: Array, w: Array, cfg: QuantConfig,
-                 site: Optional[SiteScale]) -> Array:
+                 site: Optional[SiteScale], row_parallel: bool = False
+                 ) -> Array:
     """int8 x int8 -> int32 matmul with scalar-epilogue dequant (see
     ``_int8_matmul`` for the zero-point algebra and the Pallas routing).
     Weights are quantized on the fly (constant-folds under jit when ``w``
@@ -395,11 +494,12 @@ def true_int_dot(x: Array, w: Array, cfg: QuantConfig,
         z_x = z_x - off
     xq = xq.astype(jnp.int8)
     colsum = jnp.sum(wq.astype(jnp.int32), axis=0)
-    return _int8_matmul(xq, wq, s_x, z_x, s_w, colsum, x.dtype)
+    return _int8_matmul(xq, wq, s_x, z_x, s_w, colsum, x.dtype, row_parallel)
 
 
 def prequantized_int_dot(x: Array, w: Dict[str, Array], cfg: QuantConfig,
-                         site: Optional[SiteScale]) -> Array:
+                         site: Optional[SiteScale],
+                         row_parallel: bool = False) -> Array:
     """Serving path with int8-resident weights: HBM streams 1 byte/weight
     (2x less than bf16) straight into the int8 MXU matmul — no on-the-fly
     weight requantization, no bf16 dequant materialization. The stored
@@ -425,9 +525,9 @@ def prequantized_int_dot(x: Array, w: Dict[str, Array], cfg: QuantConfig,
     xq = xq.astype(jnp.int8)
     if "w_packed" in w:
         return _int4_matmul(xq, w["w_packed"], s_x, z_x, w["w_scale"],
-                            w["colsum"], x.dtype)
+                            w["colsum"], x.dtype, row_parallel)
     return _int8_matmul(xq, w["w_int"], s_x, z_x, w["w_scale"],
-                        w["colsum"], x.dtype)
+                        w["colsum"], x.dtype, row_parallel)
 
 
 def prequantize(w: Array, cfg: QuantConfig,
@@ -508,15 +608,18 @@ def prequantize_tree(params: Any, cfg: QuantConfig,
 
 
 def qdot(x: Array, w: Any, cfg: QuantConfig,
-         site: Optional[SiteScale] = None) -> Array:
+         site: Optional[SiteScale] = None, row_parallel: bool = False
+         ) -> Array:
     """Quantized x @ w. ``w`` is (d_in, d_out) / (..., d_in, d_out), or a
-    prequantized {"w_int" | "w_packed", "w_scale", "colsum"} dict."""
+    prequantized {"w_int" | "w_packed", "w_scale", "colsum"} dict.
+    ``row_parallel``: under a tp mesh the weight's contraction dim is the
+    sharded one (the int kernels shard_map accordingly)."""
     if isinstance(w, dict):
-        return prequantized_int_dot(x, w, cfg, site)
+        return prequantized_int_dot(x, w, cfg, site, row_parallel)
     if cfg.mode == "none":
         return x @ w
     if cfg.true_int8 and w.ndim == 2 and cfg.a_bits == 8 and cfg.w_bits == 8:
-        return true_int_dot(x, w, cfg, site)
+        return true_int_dot(x, w, cfg, site, row_parallel)
     xq = act_fake_quant(x, cfg,
                         site.scale if site is not None else None,
                         site.zero if site is not None else None)

@@ -14,6 +14,14 @@ from typing import Any, Dict, Iterator, Optional
 
 import numpy as np
 
+# vocabularies up to this size keep the per-row draw (and its token stream)
+_PER_ROW_CHOICE_MAX_VOCAB = 32768
+
+
+def _has_repeat(rows: np.ndarray) -> np.ndarray:
+    s = np.sort(rows, axis=1)
+    return (s[:, 1:] == s[:, :-1]).any(axis=1)
+
 
 @dataclasses.dataclass
 class SyntheticCorpus:
@@ -25,8 +33,20 @@ class SyntheticCorpus:
     def __post_init__(self):
         rng = np.random.RandomState(self.seed)
         V, K = self.vocab_size, min(self.branching, self.vocab_size)
-        self.successors = np.stack(
-            [rng.choice(V, K, replace=False) for _ in range(V)])
+        if V <= _PER_ROW_CHOICE_MAX_VOCAB:
+            # one draw without replacement per row (a permutation of V each)
+            self.successors = np.empty((V, K), np.int64)
+            for t in range(V):
+                self.successors[t] = rng.choice(V, K, replace=False)
+        else:
+            # published vocabularies: V permutations of V would take minutes
+            # and, held as views, O(V^2) host memory — draw all rows at once
+            # and redraw the few rows with a repeated successor
+            self.successors = rng.randint(V, size=(V, K))
+            dup = _has_repeat(self.successors)
+            while dup.any():
+                self.successors[dup] = rng.randint(V, size=(dup.sum(), K))
+                dup = _has_repeat(self.successors)
         w = 1.0 / np.arange(1, K + 1) ** 1.2
         self.weights = w / w.sum()
 

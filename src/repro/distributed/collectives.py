@@ -16,8 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-
-from repro.distributed.sharding import shard_map_compat  # noqa: F401  (canonical home; re-exported for existing callers)
+from repro.distributed.sharding import shard_map
 
 
 def compressed_psum(x: jax.Array, axis_name: str) -> jax.Array:
@@ -52,7 +51,7 @@ def dp_train_step_compressed(grad_fn: Callable, mesh: Mesh,
         return loss, grads
 
     batch_spec = P(axis_name)
-    return shard_map_compat(local, mesh, in_specs=(P(), batch_spec),
+    return shard_map(local, mesh, in_specs=(P(), batch_spec),
                             out_specs=(P(), P()))
 
 

@@ -46,16 +46,11 @@ def active_mesh() -> Optional[Mesh]:
     return _ACTIVE_MESH.get()
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions: the top-level alias (with its
-    `check_vma` kwarg) appeared after 0.4.x; older releases expose
-    jax.experimental.shard_map with `check_rep` instead."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    """``jax.shard_map`` with the replication check off: the bodies are
+    Pallas kernels and hand-placed collectives it cannot see through."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _resolve_role(role, mesh: Mesh):

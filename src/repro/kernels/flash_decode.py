@@ -8,20 +8,29 @@ dominant roofline term), and (c) never expand GQA kv-heads in HBM.
 
 Layout / grid
 -------------
-q: (B, H, hd) single-token queries, reshaped to (B, K, G, hd) so each grid
-program owns one (batch, kv-head) pair and its G query heads. The KV cache
-keeps the model's native (B, Smax, K, hd) layout; the kv-head is selected in
-the BlockSpec index maps (``b % K``) — GQA needs no `jnp.repeat`, no head
-materialization, no transpose of the cache. Grid = (B*K, Smax/bkv) with the
+The KV cache keeps the model's native (B, Smax, K, hd) layout, and each
+grid program reads one ``(1, bkv, K, hd)`` chunk holding *all* K kv-heads
+of a batch row: the block's last two dims are then the array's full
+(K, hd) dims, which is what the TPU compiler accepts for this layout (a
+per-head ``(1, bkv, 1, hd)`` block has a second-minor dim of 1, neither
+8-aligned nor the full K, and is refused). Grid = (B, Smax/bkv) with the
 KV-chunk axis innermost and sequential: online-softmax partial (max, sum,
-acc) statistics live in VMEM scratch and are combined across chunks exactly
-like flash-decoding's split-KV reduction.
+acc) statistics live in VMEM scratch per (query group, kv-head) and are
+combined across chunks exactly like flash-decoding's split-KV reduction.
+Scores are a VPU multiply + lane reduction over hd per kv-head — a decode
+query is one row per head, far too thin for the MXU — so GQA needs no
+`jnp.repeat`, no head materialization and no transpose of the cache: q is
+regrouped to (B, G, K, hd) and each of the G query heads sharing a kv-head
+is folded against the same chunk. The chunk index map clamps chunks past
+the row's ``pos`` onto its last live chunk, so they are neither computed
+nor fetched again.
 
 Masking comes from the live ``pos`` value — a scalar shared by the batch or
 a per-row ``(B,)`` vector (the continuous-batching scheduler gives every
-cache slot its own decode position): chunks entirely beyond the row's
-``pos`` skip their compute via ``pl.when`` (their DMA still happens — the
-price of static shapes), and the tail chunk is masked per-position. A row
+cache slot its own decode position), scalar-prefetched into SMEM: chunks
+entirely beyond the row's ``pos`` skip their compute via ``pl.when`` and
+their DMA via the clamped index map, and the tail chunk is masked
+per-position. A row
 with ``pos < 0`` is *retired*: it attends to nothing (fp mode -> zeros) or
 to the always-visible cushion block only (int8+cushion mode). The
 continuous-batching scheduler compute-masks dead slots by *freezing* their
@@ -32,7 +41,7 @@ never write, and the jnp fallback/oracle honor the same semantics.
 int8-KV variant
 ---------------
 When per-(layer,head) scales are provided, k/v refs are int8 and are
-dequantized in-kernel (one scalar multiply per tile, fused on the VPU).
+dequantized in-kernel (one per-head multiply per tile, fused on the VPU).
 The cushion/sink prefix block [0:m) is NOT quantized: following
 KVSink/IntactKV, sink-token KV must stay intact or the whole softmax
 distribution degrades. It is read from a separate full-precision ref
@@ -48,8 +57,8 @@ table instead of dense per-row caches: the KV store is a flat page pool
 ``(n_pages, page_size, K, hd)`` and each batch row owns a ``(P,)`` row of
 the scalar-prefetched ``page_table`` mapping logical page ``j`` (cache
 positions ``[j*ps, (j+1)*ps)``) to a physical page. The only change is the
-k/v BlockSpec index map — ``(b // K, j, ...)`` becomes
-``(page_table[b // K, j], 0, ...)`` — the grid, masking arithmetic (``kj``
+k/v BlockSpec index map — ``(b, j, 0, 0)`` becomes
+``(page_table[b, j], 0, 0, 0)`` — the grid, masking arithmetic (``kj``
 stays the *logical* position) and scratch reduction are untouched, so a
 page table that happens to be the identity reproduces the contiguous
 kernel bit-for-bit at matched chunk size. Unmapped logical pages point at
@@ -84,79 +93,136 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
+# VMEM budget for one f32 (bkv, K, hd) chunk temporary: the contiguous
+# kernel shrinks its chunk until the tile fits (hd pads to 128 lanes)
+_CHUNK_F32_BYTES = 1 << 20
 
-def _kernel(*refs, bkv: int, n_kv: int, cushion_m: int, mp: int,
-            quantized: bool, scale: float):
-    pos_ref, q_ref, k_ref, v_ref = refs[:4]
-    i = 4
+
+def _kernel(pos_ref, q_ref, k_ref, v_ref, *refs, bkv: int, n_kv: int,
+            cushion_m: int, quantized: bool, scale: float):
+    i = 0
     if quantized:
-        ks_ref, vs_ref = refs[i], refs[i + 1]
-        i += 2
+        ks_ref, vs_ref = refs[0], refs[1]
+        i = 2
     if cushion_m:
         kc_ref, vc_ref = refs[i], refs[i + 1]
         i += 2
     o_ref = refs[i]
     m_ref, l_ref, acc_ref = refs[i + 1:i + 4]
 
-    j = pl.program_id(1)
-    pos = pos_ref[0]
-    q = q_ref[0, 0].astype(jnp.float32)                  # (Gp, hd)
-    Gp = q.shape[0]
+    b, j = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+    n_groups = q_ref.shape[1]
+
+    def fold(g, k, v, valid):
+        """Fold one (T, K, hd) block into query group g's online softmax."""
+        qg = q_ref[0, g].astype(jnp.float32)             # (K, hd)
+        s = jnp.sum(k * qg[None], axis=-1, keepdims=True) * scale  # (T,K,1)
+        if valid is not None:
+            s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[g]                                # (K, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        p = jnp.exp(s - m_new[None])
+        if valid is not None:
+            p = jnp.where(valid, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=0)
+        acc_ref[g] = acc_ref[g] * alpha + jnp.sum(p * v, axis=0)
+        m_ref[g] = m_new
 
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    if cushion_m:
-        # Fold the protected fp cushion block [0:m) once, as the first
-        # online-softmax block (every decode query sees the full sink block).
-        @pl.when(j == 0)
-        def _cushion():
-            kc = kc_ref[:, 0].astype(jnp.float32)        # (mp, hd)
-            vc = vc_ref[:, 0].astype(jnp.float32)
-            s = jax.lax.dot_general(q, kc, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) * scale
-            jc = jax.lax.broadcasted_iota(jnp.int32, (Gp, mp), 1)
-            valid = jc < cushion_m
-            s = jnp.where(valid, s, NEG_INF)
-            m0 = jnp.max(s, axis=-1, keepdims=True)
-            p = jnp.where(valid, jnp.exp(s - m0), 0.0)
-            l_ref[...] = jnp.sum(p, axis=-1, keepdims=True)
-            acc_ref[...] = jax.lax.dot_general(
-                p, vc, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m0
+        if cushion_m:
+            # the protected fp cushion block [0:m) is the first
+            # online-softmax block (every decode query sees the sink block)
+            kc = kc_ref[...].astype(jnp.float32)         # (m, K, hd)
+            vc = vc_ref[...].astype(jnp.float32)
+            for g in range(n_groups):
+                fold(g, kc, vc, None)
 
     @pl.when(j * bkv <= pos)       # chunks fully beyond pos: skip compute
     def _chunk():
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (bkv, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)                 # (bkv, K, hd)
+        v = v_ref[0].astype(jnp.float32)
         if quantized:
-            k = k * ks_ref[0]
-            v = v * vs_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        kj = j * bkv + jax.lax.broadcasted_iota(jnp.int32, (Gp, bkv), 1)
+            k = k * ks_ref[0][None]                      # (1, K, 1) scales
+            v = v * vs_ref[0][None]
+        kj = j * bkv + jax.lax.broadcasted_iota(jnp.int32, k.shape[:2] + (1,),
+                                                0)
         valid = kj <= pos
         if cushion_m:
             valid &= kj >= cushion_m      # [0:m) lives in the fp cushion ref
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        for g in range(n_groups):
+            fold(g, k, v, valid)
 
     @pl.when(j == n_kv - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-                       ).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
+
+
+def _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, *, bkv, n_kv,
+                 kv_index, page_table, name, interpret):
+    """Build and run the decode pallas_call. ``kv_index(b, j, *prefetch)``
+    maps a grid point to the k/v chunk; ``page_table`` (or None) is the
+    extra scalar-prefetch operand ahead of ``pos``."""
+    B, H, hd = q.shape
+    K = k.shape[2]
+    G = H // K
+    quantized = k_scale is not None
+    m = 0 if kc is None else kc.shape[0]
+    qg = q.reshape(B, K, G, hd).transpose(0, 2, 1, 3)     # (B, G, K, hd)
+    # scalar pos -> broadcast; (B,) pos -> one entry per batch row
+    posa = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    prefetch = [posa] if page_table is None else [
+        jnp.asarray(page_table, jnp.int32), posa]
+
+    in_specs = [
+        pl.BlockSpec((1, G, K, hd), lambda b, j, *_: (b, 0, 0, 0)),
+        pl.BlockSpec((1, bkv, K, hd), kv_index),
+        pl.BlockSpec((1, bkv, K, hd), kv_index),
+    ]
+    args = [qg, k, v]
+    if quantized:
+        if jnp.ndim(k_scale) == 2:      # per-row (B, K) slot scales
+            sspec = pl.BlockSpec((1, K, 1), lambda b, j, *_: (b, 0, 0))
+        else:                           # (K,) shared by the batch
+            sspec = pl.BlockSpec((1, K, 1), lambda b, j, *_: (0, 0, 0))
+        in_specs += [sspec, sspec]
+        args += [jnp.asarray(s, jnp.float32).reshape(-1, K, 1)
+                 for s in (k_scale, v_scale)]
+    if m:
+        cspec = pl.BlockSpec((m, K, hd), lambda b, j, *_: (0, 0, 0))
+        in_specs += [cspec, cspec]
+        args += [kc, vc]
+
+    def kernel(*refs):
+        # drop the page table (index maps only); keep pos
+        _kernel(*refs[len(prefetch) - 1:], bkv=bkv, n_kv=n_kv, cushion_m=m,
+                quantized=quantized, scale=1.0 / float(np.sqrt(hd)))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, n_kv),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, G, K, hd), lambda b, j, *_: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((G, K, 1), jnp.float32),
+                        pltpu.VMEM((G, K, 1), jnp.float32),
+                        pltpu.VMEM((G, K, hd), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        name=name,
+        out_shape=jax.ShapeDtypeStruct((B, G, K, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, *args)
+    return out.transpose(0, 2, 1, 3).reshape(B, H, hd)
 
 
 @functools.partial(jax.jit, static_argnames=("bkv", "interpret"))
@@ -185,22 +251,16 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos,
         stays visible to retired rows). Batch-free — the CushionCache is
         shared across sequences.
 
-    Returns (B, H, hd). VMEM working set per program:
-        G*hd (q) + 2*bkv*hd (kv tile) + G*bkv (p) + G*hd fp32 (acc).
+    ``bkv`` is an upper bound on the chunk: it shrinks until one f32
+    (bkv, K, hd) tile fits ``_CHUNK_F32_BYTES`` and until it divides Smax.
+    Returns (B, H, hd).
     """
-    B, H, hd = q.shape
-    Smax, K = k.shape[1], k.shape[2]
-    G = H // K
-    quantized = k_scale is not None
-    m = 0 if kc is None else kc.shape[0]
-    assert quantized or m == 0, "fp caches hold the cushion in-cache"
-
-    Gp = -(-G // 8) * 8                # sublane-align the query-head block
-    q4 = q.reshape(B, K, G, hd)
-    if Gp != G:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
+    Smax, K, hd = k.shape[1], k.shape[2], k.shape[3]
+    assert k_scale is not None or kc is None, \
+        "fp caches hold the cushion in-cache"
     bkv = min(bkv, Smax)
-    while Smax % bkv and bkv > 8:
+    row_bytes = K * (-(-hd // 128) * 128) * 4
+    while bkv > 8 and (Smax % bkv or bkv * row_bytes > _CHUNK_F32_BYTES):
         # prefer a chunk size that divides Smax: a ragged tail would force a
         # jnp.pad — a full HBM copy of the cache EVERY decode step (callers
         # size caches to multiples of 128, so this normally stops at a
@@ -211,53 +271,15 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos,
         pad = ((0, 0), (0, Tp - Smax), (0, 0), (0, 0))
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)
-    n_kv = Tp // bkv
-    mp = m
-    if m:
-        mp = -(-m // 8) * 8
-        if mp != m:
-            padc = ((0, mp - m), (0, 0), (0, 0))
-            kc = jnp.pad(kc, padc)
-            vc = jnp.pad(vc, padc)
-    # scalar pos -> broadcast; (B,) pos -> one entry per batch row, routed
-    # to its (batch, kv-head) programs through the index map below
-    posa = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    scale = 1.0 / np.sqrt(hd)
 
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, j: (b // K,)),                       # pos
-        pl.BlockSpec((1, 1, Gp, hd), lambda b, j: (b // K, b % K, 0, 0)), # q
-        pl.BlockSpec((1, bkv, 1, hd), lambda b, j: (b // K, j, b % K, 0)),
-        pl.BlockSpec((1, bkv, 1, hd), lambda b, j: (b // K, j, b % K, 0)),
-    ]
-    args = [posa, q4, k, v]
-    if quantized:
-        if jnp.ndim(k_scale) == 2:      # per-row (B, K) slot scales
-            sspec = pl.BlockSpec((1, 1), lambda b, j: (b // K, b % K))
-        else:                           # (K,) shared by the batch
-            sspec = pl.BlockSpec((1,), lambda b, j: (b % K,))
-        in_specs += [sspec, sspec]
-        args += [jnp.asarray(k_scale, jnp.float32),
-                 jnp.asarray(v_scale, jnp.float32)]
-    if m:
-        in_specs += [pl.BlockSpec((mp, 1, hd), lambda b, j: (0, b % K, 0)),
-                     pl.BlockSpec((mp, 1, hd), lambda b, j: (0, b % K, 0))]
-        args += [kc, vc]
+    def kv_index(b, j, pos_ref):
+        # chunks past the row's pos repeat its last live chunk: an
+        # unchanged block index is not fetched again
+        return (b, jnp.minimum(j, jnp.maximum(pos_ref[b], 0) // bkv), 0, 0)
 
-    out = pl.pallas_call(
-        functools.partial(_kernel, bkv=bkv, n_kv=n_kv, cushion_m=m, mp=mp,
-                          quantized=quantized, scale=scale),
-        grid=(B * K, n_kv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Gp, hd),
-                               lambda b, j: (b // K, b % K, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, K, Gp, hd), q.dtype),
-        scratch_shapes=[pltpu.VMEM((Gp, 1), jnp.float32),
-                        pltpu.VMEM((Gp, 1), jnp.float32),
-                        pltpu.VMEM((Gp, hd), jnp.float32)],
-        interpret=interpret,
-    )(*args)
-    return out[:, :, :G].reshape(B, H, hd)
+    return _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, bkv=bkv,
+                        n_kv=Tp // bkv, kv_index=kv_index, page_table=None,
+                        name="flash_decode", interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -293,72 +315,11 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     and the result is bit-exact (the paging property test's gate).
     Returns (B, H, hd).
     """
-    B, H, hd = q.shape
-    ps, K = k_pages.shape[1], k_pages.shape[2]
-    P = page_table.shape[1]
-    G = H // K
-    quantized = k_scale is not None
-    m = 0 if kc is None else kc.shape[0]
+    ps = k_pages.shape[1]
     assert ps % 8 == 0, "page_size must be sublane-aligned (multiple of 8)"
-
-    Gp = -(-G // 8) * 8
-    q4 = q.reshape(B, K, G, hd)
-    if Gp != G:
-        q4 = jnp.pad(q4, ((0, 0), (0, 0), (0, Gp - G), (0, 0)))
-    mp = m
-    if m:
-        mp = -(-m // 8) * 8
-        if mp != m:
-            padc = ((0, mp - m), (0, 0), (0, 0))
-            kc = jnp.pad(kc, padc)
-            vc = jnp.pad(vc, padc)
-    posa = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    scale = 1.0 / np.sqrt(hd)
-
-    # index maps receive the scalar-prefetched page table as a trailing ref;
-    # only the k/v maps dereference it (logical page j -> physical page)
-    in_specs = [
-        pl.BlockSpec((1,), lambda b, j, pt: (b // K,)),                  # pos
-        pl.BlockSpec((1, 1, Gp, hd), lambda b, j, pt: (b // K, b % K, 0, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda b, j, pt: (pt[b // K, j], 0, b % K, 0)),
-        pl.BlockSpec((1, ps, 1, hd),
-                     lambda b, j, pt: (pt[b // K, j], 0, b % K, 0)),
-    ]
-    args = [posa, q4, k_pages, v_pages]
-    if quantized:
-        if jnp.ndim(k_scale) == 2:          # per-row (B, K) slot scales
-            sspec = pl.BlockSpec((1, 1), lambda b, j, pt: (b // K, b % K))
-        else:                               # (K,) shared by the batch
-            sspec = pl.BlockSpec((1,), lambda b, j, pt: (b % K,))
-        in_specs += [sspec, sspec]
-        args += [jnp.asarray(k_scale, jnp.float32),
-                 jnp.asarray(v_scale, jnp.float32)]
-    if m:
-        in_specs += [
-            pl.BlockSpec((mp, 1, hd), lambda b, j, pt: (0, b % K, 0)),
-            pl.BlockSpec((mp, 1, hd), lambda b, j, pt: (0, b % K, 0))]
-        args += [kc, vc]
-
-    def kernel(pt_ref, *refs, **kw):
-        del pt_ref      # consumed by the index maps only
-        _kernel(*refs, **kw)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * K, P),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Gp, hd),
-                               lambda b, j, pt: (b // K, b % K, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((Gp, 1), jnp.float32),
-                        pltpu.VMEM((Gp, 1), jnp.float32),
-                        pltpu.VMEM((Gp, hd), jnp.float32)],
-    )
-    out = pl.pallas_call(
-        functools.partial(kernel, bkv=ps, n_kv=P, cushion_m=m, mp=mp,
-                          quantized=quantized, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, Gp, hd), q.dtype),
-        interpret=interpret,
-    )(jnp.asarray(page_table, jnp.int32), *args)
-    return out[:, :, :G].reshape(B, H, hd)
+    return _decode_call(
+        q, k_pages, v_pages, pos, k_scale, v_scale, kc, vc, bkv=ps,
+        n_kv=page_table.shape[1],
+        kv_index=lambda b, j, pt, pos_ref: (pt[b, j], 0, 0, 0),
+        page_table=page_table, name="flash_decode_paged",
+        interpret=interpret)

@@ -122,7 +122,7 @@ def decode_attention_tp(q: jax.Array, k: jax.Array, v: jax.Array, pos,
     one psum per layer. Returns q-sharded (B, H, hd)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
+    from repro.distributed.sharding import shard_map
 
     quantized = k_scale is not None
     pos_spec = P() if jnp.ndim(pos) == 0 else P(None)
@@ -133,7 +133,7 @@ def decode_attention_tp(q: jax.Array, k: jax.Array, v: jax.Array, pos,
         def body(q, k, v, pos, ksc, vsc, kc, vc):
             return flash_decode(q, k, v, pos, k_scale=ksc, v_scale=vsc,
                                 kc=kc, vc=vc, interpret=interpret)
-        f = shard_map_compat(
+        f = shard_map(
             body, mesh,
             in_specs=(hs, kvs, kvs, pos_spec, sspec, sspec,
                       P(None, axis, None), P(None, axis, None)),
@@ -142,7 +142,7 @@ def decode_attention_tp(q: jax.Array, k: jax.Array, v: jax.Array, pos,
 
     def body(q, k, v, pos):
         return flash_decode(q, k, v, pos, interpret=interpret)
-    f = shard_map_compat(body, mesh, in_specs=(hs, kvs, kvs, pos_spec),
+    f = shard_map(body, mesh, in_specs=(hs, kvs, kvs, pos_spec),
                          out_specs=hs)
     return f(q, k, v, pos)
 
@@ -182,7 +182,7 @@ def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
     Requires K % tp == 0."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
+    from repro.distributed.sharding import shard_map
 
     quantized = k_scale is not None
     pos_spec = P() if jnp.ndim(pos) == 0 else P(None)
@@ -196,7 +196,7 @@ def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
             return flash_decode_paged(q, k, v, pt, pos, k_scale=ksc,
                                       v_scale=vsc, kc=kc, vc=vc,
                                       interpret=interpret)
-        f = shard_map_compat(
+        f = shard_map(
             body, mesh,
             in_specs=(hs, pgs, pgs, pts, pos_spec, sspec, sspec, cus, cus),
             out_specs=hs)
@@ -206,14 +206,14 @@ def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
         def body(q, k, v, pt, pos, kc, vc):
             return flash_decode_paged(q, k, v, pt, pos, kc=kc, vc=vc,
                                       interpret=interpret)
-        f = shard_map_compat(
+        f = shard_map(
             body, mesh,
             in_specs=(hs, pgs, pgs, pts, pos_spec, cus, cus), out_specs=hs)
         return f(q, k_pages, v_pages, page_table, pos, kc, vc)
 
     def body(q, k, v, pt, pos):
         return flash_decode_paged(q, k, v, pt, pos, interpret=interpret)
-    f = shard_map_compat(body, mesh,
+    f = shard_map(body, mesh,
                          in_specs=(hs, pgs, pgs, pts, pos_spec),
                          out_specs=hs)
     return f(q, k_pages, v_pages, page_table, pos)

@@ -20,28 +20,42 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, w_ref, colsum_ref, scale_ref, zx_ref, o_ref, acc_ref, *,
-            n_k: int):
+def _dot(x_ref, w_ref):
+    return jax.lax.dot_general(x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
+
+
+def _kernel(x_ref, w_ref, colsum_ref, s_ref, o_ref, acc_ref, *, n_k: int):
     @pl.when(pl.program_id(2) == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32)
+    acc_ref[...] += _dot(x_ref, w_ref)
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
         # zero-point correction: (X - z)W = XW - z * colsum(W)
-        acc = acc - zx_ref[0] * colsum_ref[...][None, :].astype(jnp.float32)
-        o_ref[...] = acc * scale_ref[0]
+        # s_ref (SMEM) = [s_x * s_w, z_x]; colsum is a (1, bn) row
+        acc = acc - s_ref[1] * colsum_ref[...].astype(jnp.float32)
+        o_ref[...] = acc * s_ref[0]
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret"))
+def _raw_kernel(x_ref, w_ref, o_ref):
+    # int32 product only: the output block stays resident across k
+    @pl.when(pl.program_id(2) == 0)
+    def _init():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    o_ref[...] += _dot(x_ref, w_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "epilogue",
+                                             "interpret"))
 def w8a8_matmul(x_int: jax.Array, w_int: jax.Array, s_x, z_x, s_w,
                 colsum: jax.Array | None = None,
                 bm: int = 256, bn: int = 512, bk: int = 256,
+                epilogue: bool = True,
                 interpret: bool = False) -> jax.Array:
     """x_int: (M,K) int8; w_int: (K,N) int8; s_x/z_x/s_w scalar fp32.
     Returns fp32 (M,N) = (x - z_x) @ w * s_x * s_w.
@@ -54,9 +68,15 @@ def w8a8_matmul(x_int: jax.Array, w_int: jax.Array, s_x, z_x, s_w,
     HBM, which at prefill sizes cost more than the matmul it fed). K/N are
     weight dimensions — static per checkpoint — and must tile exactly.
 
-    colsum: optional precomputed (N,) int32 column sums of ``w_int`` — the
-    prequantized serving path stores them with the int8 weights so the
-    zero-point correction never re-reduces the weight per call."""
+    colsum: optional precomputed (N,) or (1, N) int32 column sums of
+    ``w_int`` — the prequantized serving path stores them with the int8
+    weights so the zero-point correction never re-reduces the weight per
+    call. The kernel reads them as a (1, bn) row (a rank-1 block would not
+    match the TPU's layout for the array) and the two scalars from SMEM.
+
+    ``epilogue=False`` returns the raw int32 product X_int @ W_int and
+    ignores the scalars and colsum: a contraction split across devices
+    sums these partials exactly and applies the epilogue once after."""
     M, K = x_int.shape
     K2, N = w_int.shape
     assert K == K2
@@ -68,26 +88,35 @@ def w8a8_matmul(x_int: jax.Array, w_int: jax.Array, s_x, z_x, s_w,
     # plus one masked boundary block
     bm = min(bm, -(-M // 32) * 32)
     n_k = K // bk
+    grid = (-(-M // bm), N // bn, n_k)
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
+    w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
+    o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
+    if not epilogue:
+        return pl.pallas_call(
+            _raw_kernel, grid=grid, name="w8a8_matmul",
+            in_specs=[x_spec, w_spec], out_specs=o_spec,
+            out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
+            interpret=interpret,
+        )(x_int, w_int)
     if colsum is None:
         colsum = jnp.sum(w_int.astype(jnp.int32), axis=0)   # (N,), tiny
-    colsum = colsum.astype(jnp.int32)
-    scale = (jnp.asarray(s_x, jnp.float32)
-             * jnp.asarray(s_w, jnp.float32)).reshape(1)
-    zx = jnp.asarray(z_x, jnp.float32).reshape(1)
+    colsum = colsum.astype(jnp.int32).reshape(1, N)
+    scalars = jnp.stack([
+        jnp.asarray(s_x, jnp.float32) * jnp.asarray(s_w, jnp.float32),
+        jnp.asarray(z_x, jnp.float32)]).reshape(2)
 
-    grid = (-(-M // bm), N // bn, n_k)
     return pl.pallas_call(
         functools.partial(_kernel, n_k=n_k),
         grid=grid,
+        name="w8a8_matmul",
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bn,), lambda i, j, k: (j,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
-            pl.BlockSpec((1,), lambda i, j, k: (0,)),
+            x_spec, w_spec,
+            pl.BlockSpec((1, bn), lambda i, j, k: (0, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        out_specs=o_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
-    )(x_int, w_int, colsum, scale, zx)
+    )(x_int, w_int, colsum, scalars)

@@ -103,6 +103,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.store import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import QuantConfig, get_config, reduced
 from repro.data.pipeline import Pipeline, SyntheticCorpus
 from repro.models.registry import build
@@ -292,11 +293,15 @@ def run_router(api, params, qcfg, args, bench_path=None, calib_batches=None,
 
     install_sigterm_drain()
     meshes = None
-    if args.tp > 1:
+    if args.tp > 1 or len(jax.devices()) >= args.replicas:
+        # every replica on its own device group (one device at tp=1)
         from repro.launch.mesh import make_replica_meshes
         meshes = make_replica_meshes(args.replicas, args.tp)
         print(f"[serve] {args.replicas} replicas x tp={args.tp} on disjoint "
               f"device groups")
+    else:
+        print(f"[serve] {args.replicas} replicas share "
+              f"{jax.devices()[0]} ({len(jax.devices())} device(s))")
     injector = None
     if args.chaos:
         injector = FaultInjector.parse(args.chaos, seed=args.chaos_seed)
@@ -466,6 +471,7 @@ def main(argv=None):
     ap.add_argument("--bench-json", default=None,
                     help="append a trajectory point to this file")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.chunk_tokens is not None and args.mode != "continuous":
         ap.error("--chunk-tokens requires --mode continuous (chunked "
                  "admission lives in the slot scheduler)")

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.store import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import QuantConfig, RunConfig, get_config, reduced
 from repro.data.pipeline import Pipeline, SyntheticCorpus
 from repro.distributed import sharding as SH
@@ -47,6 +48,7 @@ def main(argv=None):
     ap.add_argument("--eval-batches", type=int, default=8)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

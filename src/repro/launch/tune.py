@@ -65,6 +65,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.store import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import CushionConfig, Family, QuantConfig, get_config, \
     reduced
 from repro.core import cushioncache as CC
@@ -171,6 +172,7 @@ def main(argv=None):
     ap.add_argument("--report-json", default=None,
                     help="write the search/tune log + quality numbers here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

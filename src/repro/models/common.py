@@ -100,6 +100,12 @@ def get_site(scales: Optional[Params], name: str) -> Optional[Q.SiteScale]:
     return scales.get(name)
 
 
+# sites whose weight reads a tp-sharded input (contraction dim on "M" in
+# the serve rules: attn/wo, mlp/w_down, mamba/w_out, xlstm/w_proj)
+ROW_PARALLEL_SITES = frozenset({"o", "xo", "down", "mamba_out", "m_out",
+                                "s_out"})
+
+
 def qlinear(x: Array, w: Array, b: Optional[Array], qcfg: QuantConfig,
             scales: Optional[Params], site: str, taps: Optional[Dict],
             n_skip: int = 0) -> Array:
@@ -109,7 +115,8 @@ def qlinear(x: Array, w: Array, b: Optional[Array], qcfg: QuantConfig,
             "qerr": Q.site_qerr(x, qcfg, get_site(scales, site), n_skip),
             **Q.site_stats(x, n_skip),
         }
-    y = Q.qdot(x, w, qcfg, get_site(scales, site))
+    y = Q.qdot(x, w, qcfg, get_site(scales, site),
+               row_parallel=site in ROW_PARALLEL_SITES)
     if b is not None:
         y = y + b
     return y

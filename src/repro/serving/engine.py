@@ -193,12 +193,15 @@ class Engine:
         self.prefix_len = cushion_prefix_len(cushion)
         # served-cushion provenance, for logs and artifact cross-checks
         self.cushion_fp = cushion_fingerprint(cushion)
-        self._prefill = jax.jit(
-            lambda p, b, c: api.prefill(p, b, c, qcfg, cushion=cushion,
-                                        scales=scales))
-        self._decode = jax.jit(
-            lambda p, t, pos, c: api.decode_step(p, t, pos, c, qcfg,
-                                                 scales=scales))
+        # named step functions: the names label the compiled programs
+        def prefill(p, b, c):
+            return api.prefill(p, b, c, qcfg, cushion=cushion, scales=scales)
+
+        def decode(p, t, pos, c):
+            return api.decode_step(p, t, pos, c, qcfg, scales=scales)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
 
         def gen_loop(p, tok0, pos0, cache, rng, n_steps: int, greedy: bool):
             def step(carry, _):

@@ -56,6 +56,7 @@ import dataclasses
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import QuantConfig
@@ -218,6 +219,8 @@ class ReplicaRouter:
              "consecutive_errors": r.health.consecutive_errors,
              "heartbeat_age_s": r.health.heartbeat_age(now),
              "stragglers": len(r.health.stragglers),
+             "devices": sorted(str(d) for d in jax.tree_util.tree_leaves(
+                 r.engine.params)[0].devices()),
              **r.engine.stats.as_dict()}
             for r in self.replicas]
 

@@ -310,23 +310,26 @@ class ContinuousEngine:
         (self.stats.weight_bytes_fp, self.stats.weight_bytes_int8,
          self.stats.weight_bytes_int4) = resident_weight_bytes(self.params)
 
-        self._prefill = jax.jit(
-            lambda p, b, c: api.prefill(p, b, c, qcfg, cushion=cushion,
-                                        scales=scales))
+        # named step functions: the names label the compiled programs
+        def prefill(p, b, c):
+            return api.prefill(p, b, c, qcfg, cushion=cushion, scales=scales)
+
         # prefix-cache tail prefill: the cushion is a traced argument (the
         # shared stem extends it), one compile per (stem pages, tail) shape
-        self._prefill_cu = jax.jit(
-            lambda p, b, c, cu: api.prefill(p, b, c, qcfg, cushion=cu,
-                                            scales=scales))
+        def prefill_stem(p, b, c, cu):
+            return api.prefill(p, b, c, qcfg, cushion=cu, scales=scales)
+
         # chunked admission: chunk k>0 replays tokens [done:done+c) on the
         # B=1 fp staging row with a static pos_offset — the cushion and all
         # earlier chunks are read back out of the row as the visible prefix.
         # One compile per (pos_offset, chunk shape) pair, the same profile
         # as the prefix-cache tail path above.
-        self._prefill_re = jax.jit(
-            lambda p, b, c, po: api.prefill(p, b, c, qcfg, scales=scales,
-                                            pos_offset=po),
-            static_argnums=(3,))
+        def prefill_chunk(p, b, c, po):
+            return api.prefill(p, b, c, qcfg, scales=scales, pos_offset=po)
+
+        self._prefill = jax.jit(prefill)
+        self._prefill_cu = jax.jit(prefill_stem)
+        self._prefill_re = jax.jit(prefill_chunk, static_argnums=(3,))
         self._finalize_int8 = jax.jit(
             lambda row, S: api.finalize_staged_kv(
                 row, self._init_cache(1), cushion, S),

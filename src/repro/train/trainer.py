@@ -89,13 +89,16 @@ def replicated_shardings(tree: Any, mesh: Mesh) -> Any:
 
 
 def shard_update_step(step_fn: Callable, mesh: Mesh, var_shardings: Any,
-                      opt_shardings: Any, batch_like: Any = None):
+                      opt_shardings: Any, batch_like: Any = None,
+                      n_extra: int = 0):
     """jit-compile an ``(vars, opt_state, batch) -> (vars, opt_state,
     metrics)`` update step for `mesh`: carried state in/out under the given
     shardings and DONATED (compile-once, no per-step copies), batch leaves
     split on the "data" axis when `batch_like` (arrays or ShapeDtypeStructs;
-    only ndim matters) is given. Shared by `shard_train_step` (FSDP param
-    shardings) and `cushioncache.prefix_tune` (replicated cushion)."""
+    only ndim matters) is given. ``n_extra`` trailing arguments (frozen
+    params) keep the sharding they arrive with and are not donated. Shared
+    by `shard_train_step` (FSDP param shardings) and
+    `cushioncache.prefix_tune` (replicated cushion)."""
     if batch_like is None:
         b_sh = None
     else:
@@ -103,7 +106,8 @@ def shard_update_step(step_fn: Callable, mesh: Mesh, var_shardings: Any,
             lambda x: SH.batch_sharding(mesh, x.ndim), batch_like)
     return jax.jit(
         step_fn,
-        in_shardings=(var_shardings, opt_shardings, b_sh),
+        in_shardings=(var_shardings, opt_shardings, b_sh)
+        + (None,) * n_extra,
         out_shardings=(var_shardings, opt_shardings, None),
         donate_argnums=(0, 1))
 

@@ -82,13 +82,13 @@ def test_compressed_psum_multi_device_mean():
     the same result, equal to the fp32 mean within the shared-scale int8
     quantization error bound."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.distributed.sharding import shard_map_compat
+    from repro.distributed.sharding import shard_map
 
     rs = np.random.RandomState(3)
     x = jnp.asarray(rs.randn(2, 256).astype(np.float32) * 3.0)
     mesh = Mesh(np.asarray(jax.devices()[:2]), ("data",))
-    f = shard_map_compat(lambda v: compressed_psum(v, "data"), mesh,
-                         in_specs=P("data"), out_specs=P("data"))
+    f = shard_map(lambda v: compressed_psum(v, "data"), mesh,
+                  in_specs=P("data"), out_specs=P("data"))
     out = np.asarray(jax.jit(f)(x))
     exact = np.asarray(x).mean(axis=0)
     # each shard holds the mean; scale bound: amax/127 per element, halved

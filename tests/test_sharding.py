@@ -541,3 +541,46 @@ def test_cache_roles_uniform_across_families():
 def test_make_tp_mesh_validates_device_count():
     with pytest.raises(RuntimeError, match="xla_force_host_platform"):
         make_tp_mesh(NDEV + 1)
+
+
+# ---------------------------------------------------------------------------
+# Pallas matmul kernels under a tp mesh (shard_mapped per shard)
+# ---------------------------------------------------------------------------
+
+@need_devices(4)
+@pytest.mark.parametrize("row_parallel", [False, True], ids=["col", "row"])
+@pytest.mark.parametrize("bits", [8, 4])
+def test_int_kernels_tp4_match_unsharded(monkeypatch, bits, row_parallel):
+    """The int matmul kernels shard_map over tp=4 (the compiler cannot
+    partition a Pallas call): column-parallel shards keep their output
+    columns, row-parallel shards psum partials. W8A8 psums raw int32 and is
+    exact; W4A8 psums f32 partials and matches to f32 rounding."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro import flags
+    from repro.core import quantization as Q
+    monkeypatch.setattr(flags, "W8A8_KERNEL", "pallas")
+    monkeypatch.setattr(flags, "W4A8_KERNEL", "pallas")
+    qcfg = QuantConfig(mode="pt_static", true_int8=True)
+    site = Q.SiteScale(scale=jnp.float32(0.05), zero=jnp.float32(7.0))
+    rs = np.random.RandomState(bits + row_parallel)
+    x = jnp.asarray(rs.randn(8, 512).astype(np.float32))
+    pq = Q.prequantize(jnp.asarray(rs.randn(512, 384).astype(np.float32)
+                                   * 0.05), qcfg, weight_bits=bits)
+    ref = Q.qdot(x, pq, qcfg, site)
+    mesh = make_tp_mesh(4)
+    key = "w_int" if bits == 8 else "w_packed"
+    pq[key] = jax.device_put(pq[key], NamedSharding(
+        mesh, P("tp", None) if row_parallel else P(None, "tp")))
+    x = jax.device_put(x, NamedSharding(
+        mesh, P(None, "tp") if row_parallel else P()))
+
+    def f(x, pq):
+        with SH.use_mesh(mesh):
+            return Q.qdot(x, pq, qcfg, site, row_parallel=row_parallel)
+    out = jax.jit(f)(x, pq)
+    if bits == 8 or not row_parallel:
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    else:
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5, atol=1e-4)

@@ -59,6 +59,19 @@ def test_corpus_is_learnable():
     assert c.successors.shape[1] < 64
 
 
+def test_corpus_at_published_vocab_has_distinct_successors():
+    """A 151936-token vocabulary (Qwen1.5) builds in bulk: every row holds
+    distinct in-range successors, and the same seed gives the same rows."""
+    a = SyntheticCorpus(151936, seed=4)
+    assert a.successors.shape == (151936, a.branching)
+    assert 0 <= a.successors.min() and a.successors.max() < 151936
+    s = np.sort(a.successors, axis=1)
+    assert not (s[:, 1:] == s[:, :-1]).any()
+    np.testing.assert_array_equal(a.successors[:64],
+                                  SyntheticCorpus(151936, seed=4)
+                                  .successors[:64])
+
+
 # ---------------------------------------------------------------------------
 # checkpoints
 # ---------------------------------------------------------------------------
@@ -148,13 +161,13 @@ def test_supervisor_gives_up_without_checkpoint(tmp_path):
 
 def _check_compressed_psum(seed):
     from jax.sharding import Mesh
-    from repro.distributed.collectives import shard_map_compat
+    from repro.distributed.sharding import shard_map
     rng = np.random.RandomState(seed)
     x = jnp.asarray(rng.randn(1, 64).astype(np.float32))
     mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    out = shard_map_compat(lambda v: compressed_psum(v, "data"), mesh,
-                           in_specs=jax.sharding.PartitionSpec("data"),
-                           out_specs=jax.sharding.PartitionSpec("data"))(x)
+    out = shard_map(lambda v: compressed_psum(v, "data"), mesh,
+                    in_specs=jax.sharding.PartitionSpec("data"),
+                    out_specs=jax.sharding.PartitionSpec("data"))(x)
     scale = np.abs(np.asarray(x)).max() / 127.0
     assert np.abs(np.asarray(out) - np.asarray(x)).max() <= scale * 0.51 + 1e-7
 
