@@ -67,6 +67,11 @@ Graceful shutdown (continuous + router modes): SIGTERM and ctrl-C drain
 instead of dying mid-step — admission stops, live slots decode to
 completion, and the final ServeStats/RouterStats are printed for the
 completed prefix of the trace.
+
+A continuous run ends with one ``[serve] spans:`` line: for each host span
+the engines recorded (``serve.step`` and its ``.pages`` / ``.wait`` /
+``.retire`` phases, ``serve.admit`` and its ``.alloc`` / ``.wait`` /
+``.book`` phases, ``python.gc``), its count and p50/p99 self-time.
 """
 from __future__ import annotations
 
@@ -102,6 +107,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import monitoring as MON
 from repro.checkpoint.store import CheckpointManager
 from repro.compile_cache import enable_compile_cache
 from repro.configs import QuantConfig, get_config, reduced
@@ -280,6 +286,17 @@ def run_continuous(api, params, qcfg, args, bench_path=None, mesh=None,
                  "occupancy": occ, **eng.stats.as_dict()}
         _append_point(bench_path, point)
     return outs
+
+
+def print_span_summary() -> None:
+    """One line of the host spans the engines recorded (monitoring.span):
+    per span name, its count and p50/p99 self-time, the span's time less
+    the spans inside it. ``serve.step.wait`` is the step's token read;
+    ``serve.step`` alone is the host's own share of a step."""
+    parts = [f"{name} n={v['count']} p50={v['p50_ms']:.3f}ms "
+             f"p99={v['p99_ms']:.3f}ms"
+             for name, v in MON.span_summary().items()]
+    print("[serve] spans: " + "; ".join(parts))
 
 
 def run_router(api, params, qcfg, args, bench_path=None, calib_batches=None,
@@ -550,14 +567,17 @@ def main(argv=None):
 
     if args.mode == "continuous":
         if args.replicas > 1 or args.chaos:
-            return run_router(api, params, qcfg, args,
-                              bench_path=args.bench_json,
-                              calib_batches=calib, cushion=cushion,
-                              scales=art_scales)
-        return run_continuous(api, params, qcfg, args,
-                              bench_path=args.bench_json, mesh=mesh,
-                              calib_batches=calib, cushion=cushion,
-                              scales=art_scales)
+            out = run_router(api, params, qcfg, args,
+                             bench_path=args.bench_json,
+                             calib_batches=calib, cushion=cushion,
+                             scales=art_scales)
+        else:
+            out = run_continuous(api, params, qcfg, args,
+                                 bench_path=args.bench_json, mesh=mesh,
+                                 calib_batches=calib, cushion=cushion,
+                                 scales=art_scales)
+        print_span_summary()
+        return out
 
     batch = {k: jnp.asarray(v) for k, v in pipe.get_batch(0).items()}
 

@@ -1,4 +1,4 @@
-"""Compile-count and serving-occupancy instrumentation.
+"""Compile-count, host-sync, span and serving-occupancy instrumentation.
 
 Compile counting is built on ``jax.monitoring`` events.
 
@@ -17,14 +17,28 @@ Usage::
 
 Counters nest (each active counter sees every compile event), so a bench can
 hold an outer counter while tests open inner ones.
+
+Spans: ``span(name, uid=None)`` times a host phase of the serving loop. Each
+span is a ``jax.profiler.TraceAnnotation``, so it sits beside the device ops
+in any profiler trace, and one ``Span`` record in a process-wide ring of the
+last ``SPAN_RING`` spans, always on (a flight recorder). ``spans()`` returns
+the ring, ``span_summary()`` the per-name count and self-time percentiles.
+Python's generation-2 collections land in the same ring as ``python.gc``.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
-from typing import Dict, Iterator, List
+import gc
+import itertools
+import threading
+import time
+from typing import Dict, Iterator, List, NamedTuple, Optional
 
 import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
@@ -93,10 +107,138 @@ def host_sync(tree):
     one call = one device->host round trip (``jax.device_get`` fetches the
     whole tree in a single batch). Dispatch-blocking per-step ``float(v)``
     conversions were the original prefix_tune perf bug — anything tempted
-    to sync in a loop should batch values and come through here."""
+    to sync in a loop should batch values and come through here.
+
+    The wait is a span: ``<caller>.wait`` under the innermost open span
+    (``serve.step.wait`` inside ``serve.step``), else ``host_sync``. A
+    later ``np.asarray`` of the same array reads the host copy this call
+    made, with no second transfer."""
     for c in _sync_active:
         c.count += 1
-    return jax.device_get(tree)
+    caller = RECORDER.current()
+    with RECORDER.span(caller + ".wait" if caller else "host_sync"):
+        return jax.device_get(tree)
+
+
+# ---------------------------------------------------------------------------
+# Spans
+# ---------------------------------------------------------------------------
+
+SPAN_RING = 1 << 16
+GC_SPAN = "python.gc"
+
+
+class Span(NamedTuple):
+    """One closed span: ``time.perf_counter_ns()`` bounds, its id, the id of
+    the span open around it on the same thread (None for a root) and the
+    request uid it served (None where it serves no one request)."""
+    name: str
+    start_ns: int
+    end_ns: int
+    span_id: int
+    parent_id: Optional[int]
+    uid: Optional[int]
+
+
+class _OpenSpan:
+    """A span while it is open (``SpanRecorder.span``). A plain class, not a
+    generator, and a plain tuple in the ring: the recorder is always on."""
+    __slots__ = ("rec", "name", "uid", "sid", "parent", "stack", "ann", "t0")
+
+    def __init__(self, rec: "SpanRecorder", name: str, uid: Optional[int]):
+        self.rec, self.name, self.uid = rec, name, uid
+
+    def __enter__(self) -> int:
+        self.stack = stack = self.rec._stack()
+        self.sid = sid = next(self.rec._ids)
+        self.parent = stack[-1][1] if stack else None
+        stack.append((self.name, sid))
+        self.ann = TraceAnnotation(self.name)
+        self.t0 = time.perf_counter_ns()
+        self.ann.__enter__()
+        return sid
+
+    def __exit__(self, *exc) -> bool:
+        self.ann.__exit__(*exc)
+        t1 = time.perf_counter_ns()
+        self.stack.pop()
+        self.rec._ring.append((self.name, self.t0, t1, self.sid,
+                               self.parent, self.uid))
+        return False
+
+
+class SpanRecorder:
+    """The last ``maxlen`` closed spans, oldest first. Parents come from a
+    per-thread stack of open spans, so spans of two threads never nest."""
+
+    def __init__(self, maxlen: int = SPAN_RING):
+        self._ring: collections.deque = collections.deque(maxlen=maxlen)
+        self._ids = itertools.count(1)
+        self._local = threading.local()
+        self._gc_open: Optional[tuple] = None
+
+    def _stack(self) -> list:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def current(self) -> Optional[str]:
+        """Name of the innermost span open on this thread."""
+        stack = self._stack()
+        return stack[-1][0] if stack else None
+
+    def span(self, name: str, uid: Optional[int] = None) -> _OpenSpan:
+        """Context manager timing the ``with`` block as span ``name``;
+        entering it gives the span's id."""
+        return _OpenSpan(self, name, uid)
+
+    def spans(self) -> List[Span]:
+        return [Span._make(r) for r in self._ring]
+
+    def summary(self) -> Dict[str, dict]:
+        """Per span name: count, p50 and p99 self-time in ms (duration less
+        the spans directly inside it)."""
+        recs = self.spans()
+        inner: Dict[int, int] = {}
+        for s in recs:
+            if s.parent_id is not None:
+                inner[s.parent_id] = (inner.get(s.parent_id, 0)
+                                      + s.end_ns - s.start_ns)
+        own: Dict[str, list] = {}
+        for s in recs:
+            own.setdefault(s.name, []).append(
+                s.end_ns - s.start_ns - inner.get(s.span_id, 0))
+        return {name: {"count": len(v),
+                       "p50_ms": float(np.percentile(v, 50)) * 1e-6,
+                       "p99_ms": float(np.percentile(v, 99)) * 1e-6}
+                for name, v in sorted(own.items())}
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: a generation-2 collection becomes a
+        ``python.gc`` span inside whatever span its thread has open."""
+        if info["generation"] != 2:
+            return
+        if phase == "start":
+            ann = TraceAnnotation(GC_SPAN)
+            ann.__enter__()
+            self._gc_open = (time.perf_counter_ns(), ann)
+        elif self._gc_open is not None:
+            t0, ann = self._gc_open
+            t1 = time.perf_counter_ns()
+            ann.__exit__(None, None, None)
+            self._gc_open = None
+            stack = self._stack()
+            self._ring.append((GC_SPAN, t0, t1, next(self._ids),
+                               stack[-1][1] if stack else None, None))
+
+
+RECORDER = SpanRecorder()
+span = RECORDER.span
+spans = RECORDER.spans
+span_summary = RECORDER.summary
+gc.callbacks.append(RECORDER.on_gc)
 
 
 def resident_weight_bytes(params) -> tuple:

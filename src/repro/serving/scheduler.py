@@ -32,6 +32,14 @@ Design
   reads the sampled tokens; the freed slot is recycled by the next
   admission. TTFT/TPOT are tracked per request; pool occupancy lands in
   ``monitoring.ServeStats``.
+* The blocking paths record host spans (``monitoring.span``): each decode
+  step is a ``serve.step`` whose children are ``.pages`` (page mapping and
+  the page-table upload), ``.wait`` (the token read) and ``.retire``; the
+  dispatch is its self-time. Each blocking admission is a ``serve.admit``
+  carrying the request's uid, with ``.alloc`` (slot and page claim),
+  ``.wait`` (the first-token read) and ``.book``; the row, the prefill and
+  the scatter dispatch are its self-time. Both reads go through
+  ``monitoring.host_sync``, one counted sync each.
 
 Incremental API (the replica router's contract, serving/router.py):
 ``start()`` resets the pool and opens a serving session; ``try_admit(req)``
@@ -128,7 +136,8 @@ import numpy as np
 from repro.configs.base import QuantConfig
 from repro.distributed import sharding as SH
 from repro.models.registry import ModelAPI
-from repro.monitoring import ServeStats, resident_weight_bytes
+from repro.monitoring import (ServeStats, host_sync, resident_weight_bytes,
+                              span)
 from repro.serving.engine import (bucket_steps, cache_seq_len,
                                   cushion_fingerprint, cushion_prefix_len,
                                   plan_quantization,
@@ -626,7 +635,8 @@ class ContinuousEngine:
                 and not ({"patches", "frames"} & set(req.batch))
                 and req.batch["tokens"].shape[1] > self._chunk_budget()):
             return self._start_stream(req, free[0])
-        return self._admit_request(req, free[0])
+        with span("serve.admit", uid=req.uid):
+            return self._admit_request(req, free[0])
 
     def _chunk_budget(self) -> int:
         """Per-step prefill token budget. Fixed ``chunk_tokens`` unless
@@ -653,35 +663,40 @@ class ContinuousEngine:
             self._advance_stream()
         if not self.live.any():
             return []
-        live_idx = np.flatnonzero(self.live)
-        if self.paged:
-            # map this step's write page for every live slot from its
-            # admission reservation (lazy allocate-on-append), then mirror
-            # any table change to the device before the kernel reads it
-            for slot in live_idx:
-                self._pool.ensure_mapped(int(slot), int(self._hpos[slot]))
-            if self._pool.dirty:
-                self._sync_page_table()
-        with SH.use_mesh(self.mesh):
-            self.tok, self.pos, self.cache = self._step(
-                self.params, self.tok, self.pos, jnp.asarray(self.live),
-                self.cache, self.cushion_block)
-        if self.paged:
-            self._hpos[live_idx] += 1   # mirror the device pos advance
-        toks = np.asarray(self.tok)     # the one host sync per step
-        self.stats.steps += 1
-        self.stats.live_slot_steps += int(self.live.sum())
-        retired: List[int] = []
-        for slot in live_idx:
-            s = self._slots[slot]
-            req = s.req
-            s.tokens.append(int(toks[slot]))
-            if (len(s.tokens) >= req.max_new_tokens
-                    or (req.eos_id is not None
-                        and s.tokens[-1] == req.eos_id)):
-                retired.append(req.uid)
-                self._retire(int(slot))
-        return retired
+        with span("serve.step"):
+            live_idx = np.flatnonzero(self.live)
+            if self.paged:
+                # map this step's write page for every live slot from its
+                # admission reservation (lazy allocate-on-append), then
+                # mirror any table change to the device before the kernel
+                # reads it
+                with span("serve.step.pages"):
+                    for slot in live_idx:
+                        self._pool.ensure_mapped(int(slot),
+                                                 int(self._hpos[slot]))
+                    if self._pool.dirty:
+                        self._sync_page_table()
+            with SH.use_mesh(self.mesh):
+                self.tok, self.pos, self.cache = self._step(
+                    self.params, self.tok, self.pos, jnp.asarray(self.live),
+                    self.cache, self.cushion_block)
+            if self.paged:
+                self._hpos[live_idx] += 1   # mirror the device pos advance
+            toks = host_sync(self.tok)      # the one host sync per step
+            with span("serve.step.retire"):
+                self.stats.steps += 1
+                self.stats.live_slot_steps += int(self.live.sum())
+                retired: List[int] = []
+                for slot in live_idx:
+                    s = self._slots[slot]
+                    req = s.req
+                    s.tokens.append(int(toks[slot]))
+                    if (len(s.tokens) >= req.max_new_tokens
+                            or (req.eos_id is not None
+                                and s.tokens[-1] == req.eos_id)):
+                        retired.append(req.uid)
+                        self._retire(int(slot))
+            return retired
 
     def cancel(self, uid: int) -> bool:
         """Free the slot holding ``uid`` without producing a result
@@ -736,41 +751,29 @@ class ContinuousEngine:
         return need
 
     def _admit_request(self, req: Request, slot: int) -> bool:
-        need = self._check_capacity(req)
-        if self.paged:
-            return self._admit_request_paged(req, slot, need)
-        tpf = time.perf_counter()
-        with SH.use_mesh(self.mesh):
-            row = self._shard_cache(self._init_cache(1))
-            logits, row, rpos = self._prefill(self.params, req.batch, row)
-            logits = logits[:, -1] if logits.ndim == 3 else logits
-            tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            self.cache, self.pos, self.tok = self._admit(
-                self.cache, row, jnp.asarray(slot, jnp.int32), self.pos,
-                self.tok, rpos, tok0)
-        first = int(jax.block_until_ready(tok0))
-        self._book_admission(req, slot, first, tpf)
-        return True
-
-    def _admit_request_paged(self, req: Request, slot: int,
-                             need: int) -> bool:
-        """Paged admission: claim pages (full reservation — mid-decode
-        exhaustion is impossible), prefill the B=1 row contiguously, then
-        scatter each owned prompt page to its physical page. On a
-        prefix-cache hit the donor's read-only stem pages are mapped
-        (refcount++) and only the tail is prefilled against the extended
-        cushion. Returns False (backpressure) when the page pool can't
-        host the request right now."""
-        prefill_end = need - req.max_new_tokens     # prefix + prompt
-        tokens = None
-        shared: List[int] = []
-        if (self._prefix_cache
-                and not ({"patches", "frames"} & set(req.batch))):
-            tokens = np.asarray(req.batch["tokens"][0])
-            shared = self._pool.lookup_stem(tokens)
-        scatter = self._pool.admit(slot, prefill_end, need, shared=shared)
-        if scatter is None:
-            return False        # page-pool backpressure: retryable
+        """Blocking admission: B=1 prefill, then the full-row scatter into
+        ``slot`` (dense pool) or the page scatter (paged pool). Paged, it
+        first claims pages (full reservation — mid-decode exhaustion is
+        impossible) and scatters each owned prompt page to its physical
+        page. On a prefix-cache hit the donor's read-only stem pages are
+        mapped (refcount++) and only the tail is prefilled against the
+        extended cushion. Returns False (backpressure) when the page pool
+        can't host the request right now."""
+        with span("serve.admit.alloc"):
+            need = self._check_capacity(req)
+            prefill_end = need - req.max_new_tokens     # prefix + prompt
+            tokens = None
+            shared: List[int] = []
+            scatter = None
+            if self.paged:
+                if (self._prefix_cache
+                        and not ({"patches", "frames"} & set(req.batch))):
+                    tokens = np.asarray(req.batch["tokens"][0])
+                    shared = self._pool.lookup_stem(tokens)
+                scatter = self._pool.admit(slot, prefill_end, need,
+                                           shared=shared)
+                if scatter is None:
+                    return False    # page-pool backpressure: retryable
         tpf = time.perf_counter()
         with SH.use_mesh(self.mesh):
             row = self._shard_cache(self._init_cache(1))
@@ -791,15 +794,23 @@ class ContinuousEngine:
                                                   row)
             logits = logits[:, -1] if logits.ndim == 3 else logits
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
-            self.cache, self.pos, self.tok = self._admit_paged(
-                self.cache, row, jnp.asarray(slot, jnp.int32), self.pos,
-                self.tok, rpos, tok0, jnp.asarray(scatter))
-        first = int(jax.block_until_ready(tok0))
-        if tokens is not None:
-            self._pool.register_stem(slot, tokens, prefill_end)
-        self._hpos[slot] = prefill_end
-        self._book_admission(req, slot, first, tpf)
-        self._publish_gauges()
+            sl = jnp.asarray(slot, jnp.int32)
+            if self.paged:
+                self.cache, self.pos, self.tok = self._admit_paged(
+                    self.cache, row, sl, self.pos, self.tok, rpos, tok0,
+                    jnp.asarray(scatter))
+            else:
+                self.cache, self.pos, self.tok = self._admit(
+                    self.cache, row, sl, self.pos, self.tok, rpos, tok0)
+        first = int(host_sync(tok0))
+        with span("serve.admit.book"):
+            if tokens is not None:
+                self._pool.register_stem(slot, tokens, prefill_end)
+            if self.paged:
+                self._hpos[slot] = prefill_end
+            self._book_admission(req, slot, first, tpf)
+            if self.paged:
+                self._publish_gauges()
         return True
 
     def _stem_cushion(self, shared: List[int]):
