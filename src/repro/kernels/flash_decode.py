@@ -53,20 +53,29 @@ out of the int8 read.
 Paged variant
 -------------
 ``flash_decode_paged`` reads the same online-softmax body through a page
-table instead of dense per-row caches: the KV store is a flat page pool
-``(n_pages, page_size, K, hd)`` and each batch row owns a ``(P,)`` row of
-the scalar-prefetched ``page_table`` mapping logical page ``j`` (cache
-positions ``[j*ps, (j+1)*ps)``) to a physical page. The only change is the
-k/v BlockSpec index map — ``(b, j, 0, 0)`` becomes
-``(page_table[b, j], 0, 0, 0)`` — the grid, masking arithmetic (``kj``
-stays the *logical* position) and scratch reduction are untouched, so a
-page table that happens to be the identity reproduces the contiguous
-kernel bit-for-bit at matched chunk size. Unmapped logical pages point at
-the reserved scratch page 0; their positions are always masked (beyond
-``pos`` or below the cushion), so scratch content is don't-care. Unlike
-the contiguous entry, fp pools may pass a cushion block here: paging moves
-the cushion out of the per-slot rows into one shared batch-free ref for
-every dtype (serving/paging.py).
+table instead of dense per-row caches. The KV store is the whole page pool
+of every layer, lane-dense: ``(L, n_pages, ps // r, K, r*hd)`` with
+``r = page_rows(hd)`` consecutive positions side by side in the lanes of
+each head (two for hd 64, filling the 128 lanes of a vreg row), so XLA keeps
+it row-major with no lane padding and neither the decode step nor the
+admission scatter re-lays it out (``pack_pages`` / ``unpack_pages``
+convert). Each batch row owns a ``(P,)`` row of the scalar-prefetched
+``page_table`` mapping logical page ``j`` (cache positions
+``[j*ps, (j+1)*ps)``) to a physical page; the layer is one more
+scalar-prefetch operand. The k/v BlockSpec is
+``(None, None, ps // r, K, r*hd)`` at ``(layer, page_table[b, j], 0, 0, 0)``:
+one page of one layer. Inside the kernel the ``r`` positions of each lane
+row are split by static ``hd``-lane slices stacked on a major axis, which
+merges into the ``(ps, K, hd)`` tile of the contiguous kernel without a
+sublane relayout, and feed the unchanged fold. The grid, masking
+arithmetic (``kj`` stays the *logical* position) and scratch reduction are
+those of the contiguous entry, so a page table that happens to be the
+identity reproduces the contiguous kernel bit-for-bit at matched chunk
+size. Unmapped logical pages point at the reserved scratch page 0; their
+positions are always masked (beyond ``pos`` or below the cushion), so
+scratch content is don't-care. Unlike the contiguous entry, fp pools may
+pass a cushion block here: paging moves the cushion out of the per-slot
+rows into one shared batch-free ref for every dtype (serving/paging.py).
 
 Tensor parallelism
 ------------------
@@ -78,8 +87,8 @@ replicated fp cushion block sliced to local heads on entry (the stored
 block stays whole on every shard; see models/*.cache_roles). Requires
 K % tp == 0; model code falls back to the unsharded entry otherwise.
 ``decode_attention_tp_paged`` does the same for the paged entry with the
-page table replicated (page ids are shard-local row metadata, identical
-on every shard).
+page table and layer index replicated (page ids are shard-local row
+metadata, identical on every shard) and the store's K axis sharded.
 """
 from __future__ import annotations
 
@@ -98,8 +107,52 @@ NEG_INF = -1e30
 _CHUNK_F32_BYTES = 1 << 20
 
 
+def page_rows(hd: int) -> int:
+    """Positions one lane row of the paged store holds per head: as many as
+    fill the 128 lanes of a vreg row (two for hd 64), else one."""
+    return 128 // hd if hd < 128 and 128 % hd == 0 else 1
+
+
+def pack_pages(x: jax.Array) -> jax.Array:
+    """Positions ``(..., ps, K, hd)`` -> the paged store's lane-dense rows
+    ``(..., ps // r, K, r*hd)``: position ``i*r + s`` of head h sits at row
+    i, lanes ``[s*hd, (s+1)*hd)``."""
+    *lead, ps, K, hd = x.shape
+    r = page_rows(hd)
+    x = x.reshape(*lead, ps // r, r, K, hd)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, ps // r, K, r * hd)
+
+
+def unpack_pages(x: jax.Array, hd: int) -> jax.Array:
+    """Inverse of ``pack_pages``: ``(..., rows, K, r*hd)`` ->
+    ``(..., rows*r, K, hd)``."""
+    *lead, rows, K, width = x.shape
+    r = width // hd
+    x = x.reshape(*lead, rows, K, r, hd)
+    return jnp.swapaxes(x, -3, -2).reshape(*lead, rows * r, K, hd)
+
+
+def write_pages(store: jax.Array, layer, page_table: jax.Array,
+                pos: jax.Array, x: jax.Array) -> jax.Array:
+    """Write one position per row into the paged store: row b's (K, hd)
+    ``x[b]`` goes to logical position ``pos[b]`` (>= 0) of ``layer``,
+    through the row's page table. The lane row holding that position is
+    read, its hd lanes replaced and the row written back whole, which XLA
+    applies to a donated store in place (live rows own distinct pages, so
+    no two rows share a lane row; retired rows all land on scratch page 0)."""
+    B, _, hd = x.shape
+    r = store.shape[-1] // hd
+    ps = store.shape[2] * r
+    phys = page_table[jnp.arange(B), pos // ps]
+    row = (pos % ps) // r
+    old = store[layer, phys, row]                        # (B, K, r*hd)
+    mine = (jnp.arange(r * hd) // hd)[None, None] == (pos % r)[:, None, None]
+    new = jnp.where(mine, jnp.tile(x.astype(store.dtype), (1, 1, r)), old)
+    return store.at[layer, phys, row].set(new)
+
+
 def _kernel(pos_ref, q_ref, k_ref, v_ref, *refs, bkv: int, n_kv: int,
-            cushion_m: int, quantized: bool, scale: float):
+            cushion_m: int, quantized: bool, lane_dense: bool, scale: float):
     i = 0
     if quantized:
         ks_ref, vs_ref = refs[0], refs[1]
@@ -113,6 +166,20 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, *refs, bkv: int, n_kv: int,
     b, j = pl.program_id(0), pl.program_id(1)
     pos = pos_ref[b]
     n_groups = q_ref.shape[1]
+
+    def tile(ref):
+        """This grid point's (bkv, K, hd) f32 chunk of the k or v ref. A
+        lane-dense (bkv // r, K, r*hd) page splits its r positions per lane
+        row by static hd-lane slices, stacked on a major axis that merges
+        into the position axis (no sublane relayout)."""
+        if not lane_dense:
+            return ref[0].astype(jnp.float32)
+        t = ref[...].astype(jnp.float32)
+        hd = q_ref.shape[-1]
+        r = t.shape[-1] // hd
+        x = jnp.stack([t[..., s * hd:(s + 1) * hd] for s in range(r)],
+                      axis=1)                            # (bkv/r, r, K, hd)
+        return x.reshape(bkv, t.shape[1], hd)
 
     def fold(g, k, v, valid):
         """Fold one (T, K, hd) block into query group g's online softmax."""
@@ -145,8 +212,8 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, *refs, bkv: int, n_kv: int,
 
     @pl.when(j * bkv <= pos)       # chunks fully beyond pos: skip compute
     def _chunk():
-        k = k_ref[0].astype(jnp.float32)                 # (bkv, K, hd)
-        v = v_ref[0].astype(jnp.float32)
+        k = tile(k_ref)                                  # (bkv, K, hd)
+        v = tile(v_ref)
         if quantized:
             k = k * ks_ref[0][None]                      # (1, K, 1) scales
             v = v * vs_ref[0][None]
@@ -164,26 +231,31 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, *refs, bkv: int, n_kv: int,
                     ).astype(o_ref.dtype)
 
 
-def _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, *, bkv, n_kv,
-                 kv_index, page_table, name, interpret):
-    """Build and run the decode pallas_call. ``kv_index(b, j, *prefetch)``
-    maps a grid point to the k/v chunk; ``page_table`` (or None) is the
-    extra scalar-prefetch operand ahead of ``pos``."""
+def _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, *, K, bkv, n_kv,
+                 kv_index, prefetch, lane_dense, name, interpret):
+    """Build and run the decode pallas_call over K kv-heads.
+    ``kv_index(b, j, *prefetch, pos_ref)`` maps a grid point to the k/v
+    block: a ``(1, bkv, K, hd)`` chunk of a dense cache, or, ``lane_dense``,
+    a ``(bkv // r, K, r*hd)`` page of one layer of the paged store
+    (``pack_pages``). ``prefetch`` holds the scalar-prefetch operands the
+    index map reads ahead of ``pos`` (the paged entry's layer and page
+    table)."""
     B, H, hd = q.shape
-    K = k.shape[2]
     G = H // K
     quantized = k_scale is not None
     m = 0 if kc is None else kc.shape[0]
     qg = q.reshape(B, K, G, hd).transpose(0, 2, 1, 3)     # (B, G, K, hd)
     # scalar pos -> broadcast; (B,) pos -> one entry per batch row
     posa = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
-    prefetch = [posa] if page_table is None else [
-        jnp.asarray(page_table, jnp.int32), posa]
+    prefetch = list(prefetch) + [posa]
 
+    r = page_rows(hd)
+    kv_spec = pl.BlockSpec((None, None, bkv // r, K, r * hd) if lane_dense
+                           else (1, bkv, K, hd), kv_index)
     in_specs = [
         pl.BlockSpec((1, G, K, hd), lambda b, j, *_: (b, 0, 0, 0)),
-        pl.BlockSpec((1, bkv, K, hd), kv_index),
-        pl.BlockSpec((1, bkv, K, hd), kv_index),
+        kv_spec,
+        kv_spec,
     ]
     args = [qg, k, v]
     if quantized:
@@ -200,9 +272,10 @@ def _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, *, bkv, n_kv,
         args += [kc, vc]
 
     def kernel(*refs):
-        # drop the page table (index maps only); keep pos
+        # drop the index maps' own operands (layer, page table); keep pos
         _kernel(*refs[len(prefetch) - 1:], bkv=bkv, n_kv=n_kv, cushion_m=m,
-                quantized=quantized, scale=1.0 / float(np.sqrt(hd)))
+                quantized=quantized, lane_dense=lane_dense,
+                scale=1.0 / float(np.sqrt(hd)))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -277,25 +350,28 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array, pos,
         # unchanged block index is not fetched again
         return (b, jnp.minimum(j, jnp.maximum(pos_ref[b], 0) // bkv), 0, 0)
 
-    return _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, bkv=bkv,
-                        n_kv=Tp // bkv, kv_index=kv_index, page_table=None,
-                        name="flash_decode", interpret=interpret)
+    return _decode_call(q, k, v, pos, k_scale, v_scale, kc, vc, K=K,
+                        bkv=bkv, n_kv=Tp // bkv, kv_index=kv_index,
+                        prefetch=(), lane_dense=False, name="flash_decode",
+                        interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                       page_table: jax.Array, pos,
+                       page_table: jax.Array, pos, layer,
                        k_scale: jax.Array | None = None,
                        v_scale: jax.Array | None = None,
                        kc: jax.Array | None = None,
                        vc: jax.Array | None = None,
                        interpret: bool = False) -> jax.Array:
-    """Single-token decode attention over a paged (possibly int8) KV pool.
+    """Single-token decode attention over one layer of a paged (possibly
+    int8) KV pool.
 
     q: (B, H, hd) — one new query per pool slot.
-    k_pages/v_pages: (n_pages, ps, K, hd) flat page store; fp, or int8 when
-        k_scale/v_scale are given ((K,) shared or per-row (B, K) scales,
-        exactly as in ``flash_decode``).
+    k_pages/v_pages: (L, n_pages, ps // r, K, r*hd) lane-dense page store
+        of every layer (``pack_pages``: r = page_rows(hd) positions per
+        lane row); fp, or int8 when k_scale/v_scale are given ((K,) shared
+        or per-row (B, K) scales, exactly as in ``flash_decode``).
     page_table: (B, P) int32 — row b's logical page j holds cache positions
         [j*ps, (j+1)*ps) and lives at physical page page_table[b, j].
         P * ps = the pool's max_seq. The table is scalar-prefetched: the
@@ -305,21 +381,25 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
     pos: () or (B,) int32 decode positions in *logical* coordinates —
         identical semantics to the contiguous kernel, including pos < 0
         retired rows.
+    layer: () int32 — the layer of the store to read, scalar-prefetched
+        beside the page table (the decode layer scan passes its index, so
+        the store is never sliced per layer).
     kc/vc: (m, K, hd) fp cushion covering logical positions [0:m). Allowed
         for BOTH fp and int8 pools: the paged layout stores the shared
         cushion once, batch-free, never in pages (pages below m stay
         scratch-mapped and masked via ``kj >= m``).
 
     The chunk size is the page size, so against ``flash_decode(bkv=ps)`` on
-    the gathered dense cache the online-softmax block sequence is identical
-    and the result is bit-exact (the paging property test's gate).
-    Returns (B, H, hd).
+    the layer's gathered dense cache the online-softmax block sequence is
+    identical and the result is bit-exact (the paging property test's
+    gate). Returns (B, H, hd).
     """
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2] * (k_pages.shape[4] // q.shape[2])
     assert ps % 8 == 0, "page_size must be sublane-aligned (multiple of 8)"
     return _decode_call(
-        q, k_pages, v_pages, pos, k_scale, v_scale, kc, vc, bkv=ps,
-        n_kv=page_table.shape[1],
-        kv_index=lambda b, j, pt, pos_ref: (pt[b, j], 0, 0, 0),
-        page_table=page_table, name="flash_decode_paged",
-        interpret=interpret)
+        q, k_pages, v_pages, pos, k_scale, v_scale, kc, vc,
+        K=k_pages.shape[3], bkv=ps, n_kv=page_table.shape[1],
+        kv_index=lambda b, j, lyr, pt, pos_ref: (lyr[0], pt[b, j], 0, 0, 0),
+        prefetch=(jnp.asarray(layer, jnp.int32).reshape(1),
+                  jnp.asarray(page_table, jnp.int32)),
+        lane_dense=True, name="flash_decode_paged", interpret=interpret)

@@ -149,25 +149,28 @@ def decode_attention_tp(q: jax.Array, k: jax.Array, v: jax.Array, pos,
 
 def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array, pos,
+                           layer,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
                            kc: jax.Array | None = None,
                            vc: jax.Array | None = None,
                            interpret: bool = False) -> jax.Array:
     """Model-level entry for the paged split-KV decode kernel. q: (B,H,hd);
-    k/v_pages: (n_pages, ps, K, hd) page store (int8 when scales given);
-    page_table: (B, P) int32 slot page tables (scalar-prefetched into the
-    kernel's index maps); pos: () or (B,) logical decode positions; kc/vc:
-    the shared batch-free cushion block (fp AND int8 pools — paging stores
-    the cushion once, outside the pages). Returns (B,H,hd)."""
-    return flash_decode_paged(q, k_pages, v_pages, page_table, pos,
+    k/v_pages: the (L, n_pages, ps // r, K, r*hd) lane-dense page store of
+    every layer (``flash_decode.pack_pages``; int8 when scales given);
+    page_table: (B, P) int32 slot page tables and layer: () int32 (both
+    scalar-prefetched into the kernel's index maps); pos: () or (B,)
+    logical decode positions; kc/vc: the shared batch-free cushion block
+    (fp AND int8 pools — paging stores the cushion once, outside the
+    pages). Returns (B,H,hd)."""
+    return flash_decode_paged(q, k_pages, v_pages, page_table, pos, layer,
                               k_scale=k_scale, v_scale=v_scale,
                               kc=kc, vc=vc, interpret=interpret)
 
 
 def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
                               v_pages: jax.Array, page_table: jax.Array,
-                              pos, mesh, axis: str = "tp",
+                              pos, layer, mesh, axis: str = "tp",
                               k_scale: jax.Array | None = None,
                               v_scale: jax.Array | None = None,
                               kc: jax.Array | None = None,
@@ -176,9 +179,10 @@ def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
     """Tensor-parallel paged decode: ``shard_map`` ``flash_decode_paged``
     over ``axis`` with per-shard head slicing, exactly as
     ``decode_attention_tp`` — the page store shards its K axis
-    ((n_pages, ps, K, hd), serving pool roles), the page table is
-    replicated (page ids are layout metadata, identical per shard), and the
-    shared cushion block is replicated and sliced to local heads on entry.
+    ((L, n_pages, ps // r, K, r*hd), serving pool roles), the page table
+    and layer index
+    are replicated (layout metadata, identical per shard), and the shared
+    cushion block is replicated and sliced to local heads on entry.
     Requires K % tp == 0."""
     from jax.sharding import PartitionSpec as P
 
@@ -187,33 +191,36 @@ def decode_attention_tp_paged(q: jax.Array, k_pages: jax.Array,
     quantized = k_scale is not None
     pos_spec = P() if jnp.ndim(pos) == 0 else P(None)
     hs = P(None, axis, None)              # (B, H, hd) heads-sharded
-    pgs = P(None, None, axis, None)       # (n_pages, ps, K, hd)
+    pgs = P(None, None, None, axis, None)     # (L, n_pages, ps/r, K, r*hd)
     pts = P(None, None)                   # (B, P) replicated
     cus = P(None, axis, None)             # (m, K, hd) sliced per shard
+    layer = jnp.asarray(layer, jnp.int32)
     if quantized:
         sspec = P(None, axis) if jnp.ndim(k_scale) == 2 else P(axis)
-        def body(q, k, v, pt, pos, ksc, vsc, kc, vc):
-            return flash_decode_paged(q, k, v, pt, pos, k_scale=ksc,
+        def body(q, k, v, pt, pos, lyr, ksc, vsc, kc, vc):
+            return flash_decode_paged(q, k, v, pt, pos, lyr, k_scale=ksc,
                                       v_scale=vsc, kc=kc, vc=vc,
                                       interpret=interpret)
         f = shard_map(
             body, mesh,
-            in_specs=(hs, pgs, pgs, pts, pos_spec, sspec, sspec, cus, cus),
+            in_specs=(hs, pgs, pgs, pts, pos_spec, P(), sspec, sspec, cus,
+                      cus),
             out_specs=hs)
-        return f(q, k_pages, v_pages, page_table, pos, k_scale, v_scale,
-                 kc, vc)
+        return f(q, k_pages, v_pages, page_table, pos, layer, k_scale,
+                 v_scale, kc, vc)
     if kc is not None:
-        def body(q, k, v, pt, pos, kc, vc):
-            return flash_decode_paged(q, k, v, pt, pos, kc=kc, vc=vc,
+        def body(q, k, v, pt, pos, lyr, kc, vc):
+            return flash_decode_paged(q, k, v, pt, pos, lyr, kc=kc, vc=vc,
                                       interpret=interpret)
         f = shard_map(
             body, mesh,
-            in_specs=(hs, pgs, pgs, pts, pos_spec, cus, cus), out_specs=hs)
-        return f(q, k_pages, v_pages, page_table, pos, kc, vc)
+            in_specs=(hs, pgs, pgs, pts, pos_spec, P(), cus, cus),
+            out_specs=hs)
+        return f(q, k_pages, v_pages, page_table, pos, layer, kc, vc)
 
-    def body(q, k, v, pt, pos):
-        return flash_decode_paged(q, k, v, pt, pos, interpret=interpret)
+    def body(q, k, v, pt, pos, lyr):
+        return flash_decode_paged(q, k, v, pt, pos, lyr, interpret=interpret)
     f = shard_map(body, mesh,
-                         in_specs=(hs, pgs, pgs, pts, pos_spec),
+                         in_specs=(hs, pgs, pgs, pts, pos_spec, P()),
                          out_specs=hs)
-    return f(q, k_pages, v_pages, page_table, pos)
+    return f(q, k_pages, v_pages, page_table, pos, layer)

@@ -5,6 +5,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.flash_decode import unpack_pages
+
 
 def w8a8_matmul_ref(x_int: jax.Array, w_int: jax.Array, s_x: jax.Array,
                     z_x: jax.Array, s_w: jax.Array) -> jax.Array:
@@ -142,6 +144,12 @@ def flash_decode_ref(q: jax.Array, k: jax.Array, v: jax.Array, pos,
     return out.reshape(B, H, hd).astype(q.dtype)
 
 
+def layer_pages(store: jax.Array, layer, hd: int) -> jax.Array:
+    """One layer of the lane-dense paged store (L, n_pages, ps // r, K,
+    r*hd) as (n_pages, ps, K, hd) (``flash_decode.unpack_pages``)."""
+    return unpack_pages(store[layer], hd)
+
+
 def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
     """Materialize a paged KV pool as the dense per-row layout:
     pages (n_pages, ps, K, hd) + page_table (B, P) -> (B, P*ps, K, hd).
@@ -156,14 +164,18 @@ def gather_pages(pages: jax.Array, page_table: jax.Array) -> jax.Array:
 
 def flash_decode_paged_ref(q: jax.Array, k_pages: jax.Array,
                            v_pages: jax.Array, page_table: jax.Array, pos,
+                           layer,
                            k_scale: jax.Array | None = None,
                            v_scale: jax.Array | None = None,
                            kc: jax.Array | None = None,
                            vc: jax.Array | None = None) -> jax.Array:
-    """Oracle for ``flash_decode_paged``: gather the page table into the
-    dense layout, then score with ``flash_decode_ref`` (the paging oracle —
-    paged attention IS dense attention over the gathered cache). fp pools
-    may carry a cushion block here (see flash_decode_paged)."""
-    return flash_decode_ref(q, gather_pages(k_pages, page_table),
-                            gather_pages(v_pages, page_table), pos,
-                            k_scale=k_scale, v_scale=v_scale, kc=kc, vc=vc)
+    """Oracle for ``flash_decode_paged``: gather the layer's pages through
+    the page table into the dense layout, then score with
+    ``flash_decode_ref`` (the paging oracle — paged attention IS dense
+    attention over the gathered cache). fp pools may carry a cushion block
+    here (see flash_decode_paged)."""
+    hd = q.shape[2]
+    return flash_decode_ref(
+        q, gather_pages(layer_pages(k_pages, layer, hd), page_table),
+        gather_pages(layer_pages(v_pages, layer, hd), page_table), pos,
+        k_scale=k_scale, v_scale=v_scale, kc=kc, vc=vc)

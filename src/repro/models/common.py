@@ -422,12 +422,16 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
     quantization, dequant and the kernel read are then all per-row).
 
     A third layout is the PAGED pool (serving/paging.py): kv carries
-    "page_table" (B, P) int32 and k/v become a flat (n_pages, ps, K, hd)
-    page store shared by all rows; logical positions are unchanged
+    "page_table" (B, P) int32 and "layer" () int32, and k/v are the whole
+    lane-dense page store of every layer, (L, n_pages, ps // r, K, r*hd)
+    with r positions side by side in each head's lanes
+    (``flash_decode.pack_pages``), shared by all rows (``decode_layers``
+    carries it through the layer scan); logical positions are unchanged
     (pos//ps selects the logical page, the table the physical one) and the
     shared fp cushion rides in batch-free kc/vc refs for BOTH fp and int8
-    pools. Writes scatter into the mapped page; reads route through
-    flash_decode_paged (TPU) or a gather + the contiguous CPU paths.
+    pools. Writes scatter into the mapped page of the layer in place;
+    reads route through flash_decode_paged (TPU) or a gather of the layer
+    + the contiguous CPU paths.
 
     Attention runs on the Pallas split-KV flash-decode kernel on TPU, or
     the jnp oracle elsewhere. Returns (y, updated kv dict).
@@ -451,19 +455,19 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
         k_wr = k.astype(kv["k"].dtype)
         v_wr = v.astype(kv["v"].dtype)
     if paged:
-        # paged pool (serving/paging.py): k/v are a flat (n_pages,ps,K,hd)
-        # page store and page_table (B,P) maps row b's logical page
-        # posv//ps to a physical page. Retired rows keep a frozen pos AND a
-        # zeroed table row, so their dummy writes land on the reserved
-        # scratch page 0 — never on a page the allocator may have recycled.
-        pt = kv["page_table"]
-        ps = kv["k"].shape[1]
+        # paged pool (serving/paging.py): k/v are the lane-dense
+        # (L,n_pages,ps/r,K,r*hd) page store and page_table (B,P) maps row
+        # b's logical page posv//ps to a physical page. Retired rows keep a
+        # frozen pos AND a zeroed table row, so their dummy writes land on
+        # the reserved scratch page 0 — never on a page the allocator may
+        # have recycled.
+        from repro.kernels.flash_decode import write_pages
+        pt, lyr = kv["page_table"], kv["layer"]
         wpos = jnp.maximum(posv, 0)     # no negative page/offset wraps
-        phys = pt[jnp.arange(B), wpos // ps]
-        cache_k = kv["k"].at[phys, wpos % ps].set(k_wr[:, 0])
-        cache_v = kv["v"].at[phys, wpos % ps].set(v_wr[:, 0])
-        cache_k = constrain(cache_k, None, None, "M")
-        cache_v = constrain(cache_v, None, None, "M")
+        cache_k = write_pages(kv["k"], lyr, pt, wpos, k_wr[:, 0])
+        cache_v = write_pages(kv["v"], lyr, pt, wpos, v_wr[:, 0])
+        cache_k = constrain(cache_k, None, None, None, "M")
+        cache_v = constrain(cache_v, None, None, None, "M")
     elif per_row:
         # each row writes at its own position (vmapped update -> scatter)
         row_wr = jax.vmap(
@@ -499,7 +503,8 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
             # sliced per shard on entry) — no collectives inside attention
             if paged:
                 out = decode_attention_tp_paged(
-                    q1, cache_k, cache_v, kv["page_table"], posv, mesh,
+                    q1, cache_k, cache_v, kv["page_table"], posv,
+                    kv["layer"], mesh,
                     k_scale=ks if quantized else None,
                     v_scale=vs if quantized else None,
                     kc=kv.get("kc"), vc=kv.get("vc"), interpret=interpret)
@@ -511,7 +516,7 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
                     kc=kv.get("kc"), vc=kv.get("vc"), interpret=interpret)
         elif paged:
             out = decode_attention_paged(
-                q1, cache_k, cache_v, kv["page_table"], posv,
+                q1, cache_k, cache_v, kv["page_table"], posv, kv["layer"],
                 k_scale=ks if quantized else None,
                 v_scale=vs if quantized else None,
                 kc=kv.get("kc"), vc=kv.get("vc"), interpret=interpret)
@@ -527,9 +532,13 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
         # gathered values equal the contiguous pool's at every visible
         # position and the masked tail underflows to exactly zero weight,
         # so paged-vs-contiguous tokens stay bit-identical on CPU too.
-        from repro.kernels.ref import flash_decode_ref, gather_pages
-        kd = gather_pages(cache_k, kv["page_table"])
-        vd = gather_pages(cache_v, kv["page_table"])
+        from repro.kernels.ref import (flash_decode_ref, gather_pages,
+                                       layer_pages)
+        hd = cfg.head_dim
+        kd = gather_pages(layer_pages(cache_k, kv["layer"], hd),
+                          kv["page_table"])
+        vd = gather_pages(layer_pages(cache_v, kv["layer"], hd),
+                          kv["page_table"])
         if quantized:
             out = flash_decode_ref(q1, kd, vd, posv, k_scale=ks, v_scale=vs,
                                    kc=kv.get("kc"), vc=kv.get("vc"))
@@ -564,6 +573,37 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
     out = out.reshape(B, 1, cfg.n_heads * cfg.head_dim)
     y = qlinear(out, p["wo"], None, qcfg, scales, "o", taps)
     return y, new
+
+
+def decode_layers(block, x: Array, layers: Params, lscales: Params,
+                  cache: Params) -> Tuple[Array, Params]:
+    """The decode layer scan: ``block(lp, lsc, h, kv) -> (h, kv)`` over the
+    stacked layer params, scales and cache. A contiguous cache scans every
+    leaf per layer (xs in, ys out). A paged cache (``page_table`` present)
+    carries its k/v page stores whole through the scan and hands the block
+    the layer index instead, so each step writes the stores in place and
+    never slices a layer out or stacks it back; its small per-layer leaves
+    (page table, int8 scales, cushion) stay in xs."""
+    if "page_table" not in cache:
+        def body(h, xs):
+            lp, lsc, kv = xs
+            return block(lp, lsc, h, kv)
+        return jax.lax.scan(body, x, (layers, lscales, cache))
+
+    stores = {key: cache[key] for key in ("k", "v")}
+    per_layer = {key: v for key, v in cache.items() if key not in stores}
+
+    def body(carry, xs):
+        h, st = carry
+        lp, lsc, kv, layer = xs
+        h, kv = block(lp, lsc, h, {**kv, **st, "layer": layer})
+        return (h, {key: kv[key] for key in st}), None
+
+    n_layers = cache["page_table"].shape[0]
+    (x, stores), _ = jax.lax.scan(
+        body, (x, stores),
+        (layers, lscales, per_layer, jnp.arange(n_layers, dtype=jnp.int32)))
+    return x, {**per_layer, **stores}
 
 
 def attention_decode(p: Params, x: Array, cache_k: Array, cache_v: Array,

@@ -386,6 +386,20 @@ def prefill(params: Params, tokens: Array, cache: Params, cfg: ModelConfig,
     return logits, cache, jnp.asarray(m + S, jnp.int32)
 
 
+def _attn_decode(p: Params, x: Array, kvd: Params, pos: Array,
+                 cfg: ModelConfig, qcfg: QuantConfig, lsc: Params):
+    """Decode one attention sublayer over its period's cache leaves. Paged
+    pools keep their per-period xs here (attention runs only every few
+    layers): the period's page store goes in as a one-layer stack read at
+    layer 0."""
+    if "page_table" not in kvd:
+        return C.attention_decode_kv(p, x, kvd, pos, cfg, qcfg, lsc, None)
+    one = {**kvd, "k": kvd["k"][None], "v": kvd["v"][None],
+           "layer": jnp.zeros((), jnp.int32)}
+    o, new = C.attention_decode_kv(p, x, one, pos, cfg, qcfg, lsc, None)
+    return o, {**kvd, "k": new["k"][0], "v": new["v"][0]}
+
+
 def decode_step(params: Params, token: Array, pos: Array, cache: Params,
                 cfg: ModelConfig, qcfg: QuantConfig, *,
                 scales: Optional[Params] = None):
@@ -409,8 +423,8 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
             sub = pp["sub"][j]
             hn = C.apply_norm(sub["ln1"], h, cfg)
             if mixer == "attn":
-                o, kvd = C.attention_decode_kv(sub["attn"], hn, kvd, pos,
-                                               cfg, qcfg, lsc, None)
+                o, kvd = _attn_decode(sub["attn"], hn, kvd, pos, cfg, qcfg,
+                                      lsc)
             else:
                 st = {"h": mh[mi], "conv": mconv[mi]}
                 o, nst = SSM.decode_mamba(sub["mamba"], hn, st, cfg, qcfg,

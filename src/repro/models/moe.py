@@ -258,8 +258,7 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
     x = C.embed_tokens(params, token[:, None], cfg)
     lscales = C.resolve_scales(scales, SITES, cfg.n_layers, qcfg)
 
-    def body(h, xs):
-        lp, lsc, kvc = xs
+    def block(lp, lsc, h, kvc):
         hn = C.apply_norm(lp["ln1"], h, cfg)
         a, kvc = C.attention_decode_kv(lp["attn"], hn, kvc, pos, cfg, qcfg,
                                        lsc, None)
@@ -269,7 +268,7 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
         h = h + y
         return h, kvc
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], lscales, cache))
+    x, cache = C.decode_layers(block, x, params["layers"], lscales, cache)
     x = C.apply_norm(params["ln_f"], x, cfg)
     logits = C.lm_head(params, x, cfg, qcfg, scales, None)
     return logits[:, 0], cache

@@ -347,8 +347,7 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
     x = C.embed_tokens(params, token[:, None], cfg)
     lscales = C.resolve_scales(scales, SITES, cfg.n_layers, qcfg)
 
-    def body(h, xs):
-        lp, lsc, kvc = xs
+    def block(lp, lsc, h, kvc):
         hn = C.apply_norm(lp["ln1"], h, cfg)
         a, kvc = C.attention_decode_kv(lp["attn"], hn, kvc, pos, cfg, qcfg,
                                        lsc, None)
@@ -357,8 +356,9 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
         h = h + C.apply_mlp(lp["mlp"], hn, cfg, qcfg, lsc, None)
         return h, kvc
 
-    # the cache dict scans layer-wise: every leaf is stacked over L
-    x, cache = jax.lax.scan(body, x, (params["layers"], lscales, cache))
+    # every cache leaf is stacked over L; a paged pool's stores ride the
+    # scan carry, written in place (common.decode_layers)
+    x, cache = C.decode_layers(block, x, params["layers"], lscales, cache)
     x = C.apply_norm(params["ln_f"], x, cfg)
     logits = C.lm_head(params, x, cfg, qcfg,
                        scales if scales is not None else None, None)

@@ -2,9 +2,10 @@
 
 The vLLM-style layout (serving/scheduler.py ``ContinuousEngine(paged=True)``)
 replaces the dense per-slot rows ``(L, n_slots, max_seq, K, hd)`` with a flat
-page store ``(L, n_pages, page_size, K, hd)`` plus a per-slot page table
-``(n_slots, P)`` (``P = max_seq // page_size``) mapping *logical* page ``j``
-of a slot — cache positions ``[j*ps, (j+1)*ps)`` — to a *physical* page.
+lane-dense page store ``(L, n_pages, page_size, K*hd)`` plus a per-slot page
+table ``(n_slots, P)`` (``P = max_seq // page_size``) mapping *logical* page
+``j`` of a slot — cache positions ``[j*ps, (j+1)*ps)`` — to a *physical*
+page.
 Memory then scales with live tokens instead of ``n_slots * max_seq``.
 
 This module is the bookkeeping half: pure numpy/host state, no jax. The

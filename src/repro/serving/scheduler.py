@@ -72,8 +72,11 @@ KV rows, and decode quantizes/dequantizes each row with its own scales
 kc/vc is batch-free and rewritten bit-identically on every admission
 (KVSink/IntactKV).
 
-Paged KV pool (``paged=True``): the dense per-slot rows become a flat page
-store ``(L, n_pages, page_size, K, hd)`` plus a per-slot page table — KV
+Paged KV pool (``paged=True``): the dense per-slot rows become a flat,
+lane-dense page store ``(L, n_pages, page_size // r, K, r*hd)`` (r
+positions side by side in each head's lanes, ``flash_decode.pack_pages``,
+so XLA keeps it row-major and unpadded, and the decode step writes it in
+place) plus a per-slot page table — KV
 memory then scales with *live tokens*, not ``n_slots * max_seq``, so more
 slots fit a fixed HBM budget. The host-side allocator (serving/paging.py
 ``PagePool``) reserves every page a request can need at admission (mid-
@@ -135,6 +138,7 @@ import numpy as np
 
 from repro.configs.base import QuantConfig
 from repro.distributed import sharding as SH
+from repro.kernels.flash_decode import pack_pages, page_rows, unpack_pages
 from repro.models.registry import ModelAPI
 from repro.monitoring import (ServeStats, host_sync, resident_weight_bytes,
                               span)
@@ -216,6 +220,18 @@ class _PrefillStream:
         return int(self.toks.shape[1])
 
 
+def scatter_pages(store, row, scatter_idx):
+    """Write a B=1 admission row (L, 1, max_seq, K, hd) into the lane-dense
+    page store (L, n_pages, ps // r, K, r*hd): logical page j of the row
+    lands on physical page scatter_idx[j]. Only the row is re-laid out;
+    the store is updated in place when its buffer is donated."""
+    L, _, rows, K, width = store.shape
+    hd = row.shape[-1]
+    ps = rows * (width // hd)
+    pages = pack_pages(row[:, 0].reshape(L, -1, ps, K, hd))
+    return store.at[:, scatter_idx].set(pages.astype(store.dtype))
+
+
 def _scatter_row(dst, src, spec, slot):
     """Write a B=1 admission row into pool slot ``slot``. ``spec`` is the
     family's batch-axis entry: an int (flat cache leaf) or a nested dict
@@ -287,10 +303,11 @@ class ContinuousEngine:
                     "paged=True needs a pageable sequence cache "
                     "(PAGED_KV_LEAVES); this family's cache is per-request "
                     "state with nothing to page")
-            if page_size % 8:
+            if page_size % 8 or page_size % page_rows(api.cfg.head_dim):
                 raise ValueError(
                     f"page_size {page_size} must be sublane-aligned "
-                    f"(multiple of 8)")
+                    f"(multiple of 8) and hold whole lane rows of the "
+                    f"store ({page_rows(api.cfg.head_dim)} positions)")
             if self.max_seq % page_size:
                 raise ValueError(
                     f"page_size {page_size} must divide the pool max_seq "
@@ -388,11 +405,7 @@ class ContinuousEngine:
             # written once at pool reset, read-only ever after.
             cache = dict(cache)
             for key in self._paged_leaves:
-                rp = row[key][:, 0]             # (L, max_seq, K, hd)
-                rp = rp.reshape(rp.shape[0], self._P, self.page_size,
-                                *rp.shape[2:])
-                cache[key] = cache[key].at[:, scatter_idx].set(
-                    rp.astype(cache[key].dtype))
+                cache[key] = scatter_pages(cache[key], row[key], scatter_idx)
             for key, ax in self._paged_axes.items():
                 cache[key] = _scatter_row(cache[key], row[key], ax, slot)
             return (cache, pos.at[slot].set(jnp.asarray(rpos, jnp.int32)),
@@ -462,18 +475,21 @@ class ContinuousEngine:
 
     def _reset_pool_paged(self) -> None:
         """Build the paged pool: the dense (L, n_slots, max_seq, K, hd) KV
-        leaves become a flat (L, n_pages, ps, K, hd) page store + an
-        (L, n_slots, P) page table; every other leaf (int8 scales, hybrid's
-        Mamba state) keeps its dense per-slot row. The fp cushion block is
-        written ONCE here into batch-free kc/vc leaves — the refcounted,
-        read-only cushion page every slot maps — and never copied again."""
+        leaves become a flat lane-dense (L, n_pages, ps/r, K, r*hd) store
+        + an (L, n_slots, P) page table; every other leaf (int8 scales,
+        hybrid's Mamba state) keeps its dense per-slot row. The fp cushion
+        block is written ONCE here into batch-free kc/vc leaves — the
+        refcounted, read-only cushion page every slot maps — and never
+        copied again."""
         shapes = jax.eval_shape(lambda: self._init_cache(self.n_slots))
         ps = self.page_size
         pool = {}
         for key, sd in shapes.items():
             if key in self._paged_leaves:
-                L, _, _, *rest = sd.shape
-                pool[key] = jnp.zeros((L, self.n_pages, ps, *rest), sd.dtype)
+                L, _, _, K, hd = sd.shape
+                store = jax.eval_shape(pack_pages, jax.ShapeDtypeStruct(
+                    (L, self.n_pages, ps, K, hd), sd.dtype))
+                pool[key] = jnp.zeros(store.shape, sd.dtype)
             elif key not in ("kc", "vc"):
                 pool[key] = jnp.zeros(sd.shape, sd.dtype)
         cu = {}
@@ -505,7 +521,7 @@ class ContinuousEngine:
         models/*.cache_roles). The admission row shares the pool's layout so
         the slot scatter is shard-local, never a reshard. The paged pool
         keeps the KV-heads axis of its page store on "M" (pages replace the
-        batch/seq dims, heads stay sharded: (L, n_pages, ps, K, hd));
+        batch/seq dims, heads stay sharded: (L, n_pages, ps/r, K, r*hd));
         the page table and the shared cushion block replicate."""
         if self.mesh is None:
             return cache
@@ -515,8 +531,8 @@ class ContinuousEngine:
             roles = dict(roles)
             for key in self._paged_leaves:
                 r = tuple(roles.get(key, ())) + (None,) * 5
-                # (L,B,S,K,hd) role -> (L,n_pages,ps,K,hd): keep the layer
-                # and heads/head-dim entries, pages/offsets replicate
+                # (L,B,S,K,hd) role -> (L,n_pages,ps/r,K,r*hd): keep the
+                # layer and heads/head-dim entries, pages/rows replicate
                 roles[key] = (r[0], None, None, r[3], r[4])
         return jax.device_put(cache, SH.cache_shardings(roles, cache,
                                                         self.mesh))
@@ -820,8 +836,9 @@ class ContinuousEngine:
         ps = self.page_size
         c0 = self._pool.c0
         donors = jnp.asarray(shared, jnp.int32)
-        kp = self.cache["k"][:, donors]             # (L, h, ps, K, hd)
-        vp = self.cache["v"][:, donors]
+        hd = self.api.cfg.head_dim
+        kp = unpack_pages(self.cache["k"][:, donors], hd)  # (L,h,ps,K,hd)
+        vp = unpack_pages(self.cache["v"][:, donors], hd)
         kp = kp.reshape(kp.shape[0], -1, *kp.shape[3:])
         vp = vp.reshape(vp.shape[0], -1, *vp.shape[3:])
         skip = self.prefix_len - c0 * ps            # cushion rows in page c0

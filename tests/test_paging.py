@@ -1,12 +1,14 @@
 """Paged KV pool contract (``serving/paging.py`` + ``flash_decode_paged``):
 
 * Kernel bit-exactness: for random permutation page tables the paged
-  Pallas kernel is BIT-identical to the contiguous kernel run at
-  ``bkv=page_size`` over the gathered cache — fp pools and int8 pools with
-  per-slot (B, K) scales and a fp cushion block, including retired rows
-  (pos == -1) reading only the scratch page. fp + cushion folds the
-  cushion in a different order than the contiguous kernel, so that
-  combination is gated against the gather oracle (allclose) instead.
+  Pallas kernel, reading one layer of a stacked lane-dense store
+  ``(L, n_pages, ps // r, K, r*hd)``, is BIT-identical to the contiguous
+  kernel run at ``bkv=page_size`` over that layer's gathered cache — every
+  layer index, fp pools and int8 pools with per-slot (B, K) scales and a fp
+  cushion block, including retired rows (pos == -1) reading only the
+  scratch page. fp + cushion folds the cushion in a different order than
+  the contiguous kernel, so that combination is gated against the gather
+  oracle (allclose) instead.
 * Allocator invariants: reservation-based admission backpressure, page
   accounting across release/re-admit, scratch page pinned forever.
 * Scheduler parity: the paged pool serves a recycling trace token-for-token
@@ -25,7 +27,8 @@ import pytest
 
 from repro.configs import QuantConfig, get_config, reduced
 from repro.kernels import ref as R
-from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.flash_decode import (flash_decode, flash_decode_paged,
+                                        pack_pages, page_rows)
 from repro.models.registry import build
 from repro.serving import ContinuousEngine, Engine, Request
 from repro.serving.paging import PagePool
@@ -42,14 +45,15 @@ QN = QuantConfig(mode="none")
 # Kernel: paged == contiguous, bit for bit
 # ---------------------------------------------------------------------------
 
-_B, _K, _G, _HD, _SMAX, _PS, _M = 4, 2, 2, 16, 64, 32, 8
+_B, _K, _G, _HD, _SMAX, _PS, _M, _L = 4, 2, 2, 16, 64, 32, 8, 3
 _P = _SMAX // _PS
 _RS = np.random.RandomState(11)
 _Q = jnp.asarray(_RS.randn(_B, _K * _G, _HD).astype(np.float32))
-_KF = _RS.randn(_B, _SMAX, _K, _HD).astype(np.float32)
-_VF = _RS.randn(_B, _SMAX, _K, _HD).astype(np.float32)
-_KQ = _RS.randint(-127, 128, (_B, _SMAX, _K, _HD)).astype(np.int8)
-_VQ = _RS.randint(-127, 128, (_B, _SMAX, _K, _HD)).astype(np.int8)
+# one dense (B, Smax, K, hd) cache per layer of the stacked store
+_KF = _RS.randn(_L, _B, _SMAX, _K, _HD).astype(np.float32)
+_VF = _RS.randn(_L, _B, _SMAX, _K, _HD).astype(np.float32)
+_KQ = _RS.randint(-127, 128, (_L, _B, _SMAX, _K, _HD)).astype(np.int8)
+_VQ = _RS.randint(-127, 128, (_L, _B, _SMAX, _K, _HD)).astype(np.int8)
 _KSR = jnp.asarray(_RS.rand(_B, _K).astype(np.float32) * 0.05 + 0.01)
 _VSR = jnp.asarray(_RS.rand(_B, _K).astype(np.float32) * 0.05 + 0.01)
 _KC = jnp.asarray(_RS.randn(_M, _K, _HD).astype(np.float32))
@@ -57,38 +61,50 @@ _VC = jnp.asarray(_RS.randn(_M, _K, _HD).astype(np.float32))
 
 
 def _paginate(k, v, seed, n_extra=3):
-    """Scatter dense (B, Smax, K, hd) rows into a random-permutation page
-    store: page 0 stays scratch (junk content — it must never influence the
-    output), logical page j of row b lands on physical page table[b, j]."""
+    """Scatter dense per-layer (L, B, Smax, K, hd) rows into a random-
+    permutation lane-dense page store (L, n_pages, ps // r, K, r*hd): page
+    0 stays scratch (junk content — it must never influence the output),
+    logical page j of row b lands on physical page table[b, j] in every
+    layer."""
     rs = np.random.RandomState(seed)
     n_pages = _B * _P + 1 + n_extra
     perm = rs.permutation(np.arange(1, n_pages))[:_B * _P]
     table = perm.reshape(_B, _P).astype(np.int32)
-    kp = rs.randn(n_pages, _PS, _K, _HD).astype(np.float32).astype(k.dtype)
-    vp = rs.randn(n_pages, _PS, _K, _HD).astype(np.float32).astype(v.dtype)
-    kp[table.reshape(-1)] = k.reshape(_B * _P, _PS, _K, _HD)
-    vp[table.reshape(-1)] = v.reshape(_B * _P, _PS, _K, _HD)
+    r = page_rows(_HD)
+    shape = (_L, n_pages, _PS // r, _K, r * _HD)
+    kp = rs.randn(*shape).astype(np.float32).astype(k.dtype)
+    vp = rs.randn(*shape).astype(np.float32).astype(v.dtype)
+    pages = lambda x: np.asarray(pack_pages(
+        jnp.asarray(x).reshape(_L, _B * _P, _PS, _K, _HD)))
+    kp[:, table.reshape(-1)] = pages(k)
+    vp[:, table.reshape(-1)] = pages(v)
     return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(table)
 
 
 def _check_paged_kernel(pos, quantized, seed=0):
+    """Every layer of the stacked store against the contiguous kernel on
+    that layer's dense cache, bit for bit."""
     posv = jnp.asarray(pos, jnp.int32)
-    if quantized:
-        kp, vp, table = _paginate(_KQ, _VQ, seed)
-        out = flash_decode_paged(_Q, kp, vp, table, posv, k_scale=_KSR,
-                                 v_scale=_VSR, kc=_KC, vc=_VC,
-                                 interpret=True)
-        # same chunk size, same online-softmax fold order -> bit-exact
-        ref = flash_decode(_Q, jnp.asarray(_KQ), jnp.asarray(_VQ), posv,
-                           k_scale=_KSR, v_scale=_VSR, kc=_KC, vc=_VC,
-                           bkv=_PS, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-    else:
-        kp, vp, table = _paginate(_KF, _VF, seed)
-        out = flash_decode_paged(_Q, kp, vp, table, posv, interpret=True)
-        ref = flash_decode(_Q, jnp.asarray(_KF), jnp.asarray(_VF), posv,
-                           bkv=_PS, interpret=True)
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+    k, v = (_KQ, _VQ) if quantized else (_KF, _VF)
+    kp, vp, table = _paginate(k, v, seed)
+    for layer in range(_L):
+        if quantized:
+            out = flash_decode_paged(_Q, kp, vp, table, posv, layer,
+                                     k_scale=_KSR, v_scale=_VSR, kc=_KC,
+                                     vc=_VC, interpret=True)
+            # same chunk size, same online-softmax fold order -> bit-exact
+            ref = flash_decode(_Q, jnp.asarray(k[layer]),
+                               jnp.asarray(v[layer]), posv, k_scale=_KSR,
+                               v_scale=_VSR, kc=_KC, vc=_VC, bkv=_PS,
+                               interpret=True)
+        else:
+            out = flash_decode_paged(_Q, kp, vp, table, posv, layer,
+                                     interpret=True)
+            ref = flash_decode(_Q, jnp.asarray(k[layer]),
+                               jnp.asarray(v[layer]), posv, bkv=_PS,
+                               interpret=True)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref),
+                                      err_msg=f"layer {layer}")
 
 
 @pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
@@ -101,8 +117,9 @@ def _check_paged_kernel(pos, quantized, seed=0):
 def test_paged_kernel_bit_identical_cases(pos, quantized):
     """Deterministic cases (always run, even without hypothesis): the paged
     kernel reproduces the contiguous kernel BIT-for-bit over permuted page
-    tables — fp, and int8 with per-slot (B, K) scales + fp cushion —
-    including fully retired rows whose table points at freed pages."""
+    tables, at every layer of the stacked store — fp, and int8 with
+    per-slot (B, K) scales + fp cushion — including fully retired rows
+    whose table points at freed pages."""
     _check_paged_kernel(pos, quantized)
 
 
@@ -126,11 +143,13 @@ def test_paged_kernel_fp_cushion_matches_oracle():
     gather oracle, not bit-identity."""
     kp, vp, table = _paginate(_KF, _VF, 3)
     posv = jnp.asarray([_M, -1, _SMAX - 1, 33], jnp.int32)
-    out = flash_decode_paged(_Q, kp, vp, table, posv, kc=_KC, vc=_VC,
-                             interpret=True)
-    ref = R.flash_decode_paged_ref(_Q, kp, vp, table, posv, kc=_KC, vc=_VC)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-4, atol=1e-4)
+    for layer in range(_L):
+        out = flash_decode_paged(_Q, kp, vp, table, posv, layer, kc=_KC,
+                                 vc=_VC, interpret=True)
+        ref = R.flash_decode_paged_ref(_Q, kp, vp, table, posv, layer,
+                                       kc=_KC, vc=_VC)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -191,8 +210,10 @@ def test_paged_scheduler_matches_engine(kv_dtype):
                           page_size=32)
     outs = ce.run(reqs)
     assert ce.stats.recycles >= 1, "trace must exercise page recycling"
-    assert ce.cache["k"].shape[1] == ce.n_pages, \
-        "paged pool must hold flat pages, not per-slot rows"
+    L, K, hd = api.cfg.n_layers, api.cfg.n_kv_heads, api.cfg.head_dim
+    r = page_rows(hd)
+    assert ce.cache["k"].shape == (L, ce.n_pages, 32 // r, K, r * hd), \
+        "paged pool must hold flat lane-dense pages, not per-slot rows"
 
     eng = Engine(api, params, QN, cushion=cushion, max_seq=128,
                  kv_dtype=kv_dtype)
@@ -202,6 +223,32 @@ def test_paged_scheduler_matches_engine(kv_dtype):
     g = ce.stats
     assert g.pages_total == ce.n_pages and g.pages_free == g.pages_total - 1
     assert g.cushion_page_refs == 1     # pool's pinned ref, no live slots
+
+
+@pytest.mark.parametrize("arch,kv_dtype", [
+    ("olmoe-1b-7b", None), ("internvl2-26b", None),
+    ("jamba-v0.1-52b", None), ("jamba-v0.1-52b", "int8"),
+], ids=["moe", "vlm", "hybrid-fp", "hybrid-int8"])
+def test_paged_family_matches_engine(arch, kv_dtype):
+    """Every family that pages its KV serves the paged pool token-for-token
+    like the static Engine: moe's and vlm's decode scans carry the store
+    by layer index like the dense one, and hybrid's period scan hands its
+    attention sublayer a one-layer view of the period's pages."""
+    api, params, cushion = _setup(arch)
+    reqs = [Request(uid=i, batch=api.make_batch(jax.random.PRNGKey(100 + i),
+                                                1, 20),
+                    max_new_tokens=n)
+            for i, n in enumerate([5, 3, 6, 4])]
+    ce = ContinuousEngine(api, params, QN, n_slots=2, max_seq=128,
+                          cushion=cushion, kv_dtype=kv_dtype, paged=True,
+                          page_size=32)
+    outs = ce.run(reqs)
+    assert ce.stats.recycles >= 1
+    eng = Engine(api, params, QN, cushion=cushion, max_seq=128,
+                 kv_dtype=kv_dtype)
+    for req, out in zip(reqs, outs):
+        ref = eng.generate(req.batch, req.max_new_tokens).tokens[0]
+        np.testing.assert_array_equal(out.tokens, ref)
 
 
 def test_page_table_syncs_flat_during_pure_decode():
