@@ -1,30 +1,32 @@
 """Operations and bytes of the kernels and of the model, from shapes.
 
-Everything here is computed from the configuration's sizes and the counts
-the harness records (slots, context lengths, prompt lengths). Bytes are the
-ones the algorithm has to move at the stored precision; padding the
+Everything here is computed from the configuration's sizes, the matrix
+products its layout names (``linear_shapes``, ``matmul_shapes``) and the
+counts the harness records (slots, context lengths, prompt lengths). Bytes
+are the ones the algorithm has to move at the stored precision; padding the
 hardware adds is not counted.
 """
 from __future__ import annotations
 
 from typing import List, Tuple
 
-from model import dims
+import model
 
 KV_BYTES = 2        # the served KV pool is bfloat16
 
 
 def linear_shapes(cfg: dict) -> List[Tuple[int, int]]:
-    """(K, N) of the W8A8 projections of one layer."""
-    n = dims(cfg)
-    d, H, K, hd, ff = n["d"], n["H"], n["K"], n["hd"], n["ff"]
-    return [(d, (H + 2 * K) * hd), (H * hd, d), (d, ff), (d, ff), (ff, d)]
+    """(K, N) of the W8A8 projections one token multiplies in one layer,
+    as the configuration's layout gives them."""
+    return model.load_layout(cfg).linear_shapes(cfg)
 
 
 def linear_params(cfg: dict) -> int:
-    """Weights every token multiplies: the projections and the LM head."""
-    n = dims(cfg)
-    per_layer = sum(k * nn for k, nn in linear_shapes(cfg))
+    """Weights every token multiplies: every matrix of a layer that the
+    layout names, W8A8 or not, in every layer, and the LM head."""
+    n = model.dims(cfg)
+    shapes = model.load_layout(cfg).matmul_shapes(cfg)
+    per_layer = sum(k * nn for k, nn in shapes)
     return n["L"] * per_layer + n["d"] * n["V"]
 
 
@@ -37,7 +39,7 @@ def w8a8_call(M: int, K: int, N: int) -> Tuple[float, float]:
 def w8a8_step_calls(cfg: dict, B: int) -> List[Tuple[float, float]]:
     """The W8A8 calls of one decode step over B slots: every projection of
     every layer, and the LM head."""
-    n = dims(cfg)
+    n = model.dims(cfg)
     calls = [w8a8_call(B, k, nn) for k, nn in linear_shapes(cfg)] * n["L"]
     return calls + [w8a8_call(B, n["d"], n["V"])]
 
@@ -52,7 +54,7 @@ def flash_decode_step(cfg: dict, B: int, sum_ctx: int) -> Tuple[float, float]:
     """Paged decode attention of one step over every layer: B queries, each
     reading the bfloat16 keys and values of its own context (cushion
     included)."""
-    n = dims(cfg)
+    n = model.dims(cfg)
     L, H, K, hd = n["L"], n["H"], n["K"], n["hd"]
     ops = 4.0 * H * hd * sum_ctx
     nbytes = 2.0 * K * hd * KV_BYTES * sum_ctx + 2.0 * B * H * hd * 2
@@ -61,7 +63,7 @@ def flash_decode_step(cfg: dict, B: int, sum_ctx: int) -> Tuple[float, float]:
 
 def decode_flops(cfg: dict, n_tokens: int, sum_ctx: int) -> float:
     """Model operations of n decode tokens whose contexts sum to sum_ctx."""
-    n = dims(cfg)
+    n = model.dims(cfg)
     return (2.0 * linear_params(cfg) * n_tokens
             + 4.0 * n["L"] * n["H"] * n["hd"] * sum_ctx)
 
@@ -70,7 +72,7 @@ def prefill_flops(cfg: dict, T: int, m: int) -> float:
     """Model operations of a T-token prompt after an m-position cushion:
     every projection for every prompt token, causal attention over the
     cushion and the prompt, and the LM head on the last position."""
-    n = dims(cfg)
+    n = model.dims(cfg)
     per_tok = 2.0 * (linear_params(cfg) - n["d"] * n["V"])
     keys = T * m + T * (T + 1) / 2.0
     return (per_tok * T + 4.0 * n["L"] * n["H"] * n["hd"] * keys

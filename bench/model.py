@@ -1,13 +1,15 @@
-"""Configuration files and seeded weights.
+"""Configuration files, their layouts and references, and seeded inputs.
 
 A configuration file (``bench/configs/<name>.json``) holds the model's
 published sizes under the keys of its public ``config.json``, how it is
-served, and the name of its plain reference (``bench/references/<name>.py``).
+served, and two names: its weight layout (``bench/layouts/<layout>.py``)
+and its plain reference (``bench/references/<reference>.py``). A new
+architecture brings a configuration, a layout and a reference as new files.
 
-The benchmark makes the weights itself, from the run's seed, on the device
-in one jitted call and in the type they are served in (bfloat16), laid out
-as the program's dense decoder takes them. The reference draws the same
-weights again after the window, so it takes nothing the program made.
+The layout makes the weights from the run's seed, on the device in one
+jitted call and in the type they are served in, laid out as the program
+takes them. The reference draws the same weights again after the window,
+so it takes nothing the program made.
 """
 from __future__ import annotations
 
@@ -21,11 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-
-# init scales of the seeded weights (also stated in each config's "assumed")
-EMBED_STD = 0.02
-BIAS_STD = 0.02
-GAIN_STD = 0.1
+# where layouts are looked for, in order
+LAYOUT_DIRS = (os.path.join(HERE, "layouts"),)
 # calibration: batches of rows x length tokens (stated in each "assumed")
 CALIB_BATCHES, CALIB_ROWS, CALIB_LEN = 2, 4, 256
 
@@ -36,38 +35,47 @@ def load_config(name: str, directory: str = os.path.join(HERE, "configs")
         return json.load(f)
 
 
-def load_reference(cfg: dict):
-    """The configuration's plain reference module, found by name."""
-    path = os.path.join(HERE, "references", f"{cfg['reference']}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_reference_{cfg['reference']}", path)
+@functools.lru_cache(maxsize=None)
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reference(cfg: dict):
+    """The configuration's plain reference module, found by name."""
+    path = os.path.join(HERE, "references", f"{cfg['reference']}.py")
+    return _module(path, f"bench_reference_{cfg['reference']}")
+
+
+def load_layout(cfg: dict):
+    """The configuration's weight layout module, found by name."""
+    for d in LAYOUT_DIRS:
+        path = os.path.join(d, f"{cfg['layout']}.py")
+        if os.path.exists(path):
+            return _module(path, f"bench_layout_{cfg['layout']}")
+    raise FileNotFoundError(f"{cfg['name']}: no layout {cfg['layout']!r} "
+                            f"in {LAYOUT_DIRS}")
 
 
 def dims(cfg: dict) -> dict:
     d = int(cfg["hidden_size"])
     H = int(cfg["num_attention_heads"])
     return {"L": int(cfg["num_hidden_layers"]), "d": d, "H": H,
-            "K": int(cfg["num_key_value_heads"]), "hd": d // H,
+            "K": int(cfg["num_key_value_heads"]),
+            "hd": int(cfg.get("head_dim") or d // H),
             "ff": int(cfg["intermediate_size"]), "V": int(cfg["vocab_size"])}
 
 
 def program_config(cfg: dict):
     """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import Family, ModelConfig
-    n = dims(cfg)
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"{cfg['name']}: only gated SiLU MLPs are served")
-    return ModelConfig(
-        name=cfg["name"], family=Family.DENSE, n_layers=n["L"],
-        d_model=n["d"], n_heads=n["H"], n_kv_heads=n["K"], d_ff=n["ff"],
-        vocab_size=n["V"], qkv_bias=bool(cfg["qkv_bias"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        max_seq_len=int(cfg["max_position_embeddings"]),
-        dtype=cfg["torch_dtype"])
+    return load_layout(cfg).program_config(cfg)
+
+
+def make_weights(cfg: dict, seed: int):
+    """Weights from the seed, in the layout the configuration names."""
+    return load_layout(cfg).make_weights(cfg, seed)
 
 
 def seed_key(seed: int):
@@ -75,60 +83,6 @@ def seed_key(seed: int):
     seed = int(seed)
     k = jax.random.key(seed & 0xFFFFFFFF)
     return jax.random.fold_in(k, (seed >> 32) & 0xFFFFFFFF)
-
-
-def _shapes(cfg: dict) -> dict:
-    n = dims(cfg)
-    L, d, H, K, hd, ff, V = (n[k] for k in ("L", "d", "H", "K", "hd", "ff",
-                                             "V"))
-    return {"embed": (V, d), "wqkv": (L, d, (H + 2 * K) * hd),
-            "bqkv": (L, (H + 2 * K) * hd), "wo": (L, H * hd, d),
-            "w_up": (L, d, ff), "w_gate": (L, d, ff), "w_down": (L, ff, d),
-            "ln1": (L, d), "ln2": (L, d), "ln_f": (d,)}
-
-
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _make(key, cfg_items: tuple, dtype: str):
-    cfg = dict(cfg_items)
-    n = dims(cfg)
-    sh = _shapes(cfg)
-    ks = dict(zip(sh, jax.random.split(key, len(sh))))
-    dt = jnp.dtype(dtype)
-    out_scale = 1.0 / np.sqrt(2 * n["L"])
-
-    def normal(name, std):
-        return (jax.random.normal(ks[name], sh[name], jnp.float32)
-                * std).astype(dt)
-
-    def gain(name):
-        return (1.0 + GAIN_STD * jax.random.normal(
-            ks[name], sh[name], jnp.float32)).astype(dt)
-
-    attn = {"wqkv": normal("wqkv", 1.0 / np.sqrt(n["d"])),
-            "wo": normal("wo", out_scale / np.sqrt(n["H"] * n["hd"]))}
-    if cfg["qkv_bias"]:
-        attn["bqkv"] = normal("bqkv", BIAS_STD)
-    mlp = {"w_up": normal("w_up", 1.0 / np.sqrt(n["d"])),
-           "w_gate": normal("w_gate", 1.0 / np.sqrt(n["d"])),
-           "w_down": normal("w_down", out_scale / np.sqrt(n["ff"]))}
-    return {"embed": {"w": normal("embed", EMBED_STD)},
-            "layers": {"ln1": {"g": gain("ln1")}, "attn": attn,
-                       "ln2": {"g": gain("ln2")}, "mlp": mlp},
-            "ln_f": {"g": gain("ln_f")}}
-
-
-def _hashable(cfg: dict) -> tuple:
-    keys = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-            "num_key_value_heads", "intermediate_size", "vocab_size",
-            "qkv_bias")
-    return tuple((k, cfg[k]) for k in keys)
-
-
-def make_weights(cfg: dict, seed: int):
-    """Weights from the seed, in the program's dense-decoder layout."""
-    if not cfg["tie_word_embeddings"]:
-        raise ValueError(f"{cfg['name']}: untied heads are not drawn yet")
-    return _make(seed_key(seed), _hashable(cfg), cfg["torch_dtype"])
 
 
 def cushion_tokens(cfg: dict, seed: int) -> np.ndarray:

@@ -21,6 +21,7 @@ spans.
 from __future__ import annotations
 
 import dataclasses
+import gc
 import statistics
 from typing import Dict, List, Optional, Tuple
 
@@ -59,7 +60,17 @@ def program_spans() -> Optional[list]:
     except ImportError:
         return None
     read = getattr(monitoring, "spans", None)
-    return None if read is None else [tuple(s) for s in read()]
+    if read is None:
+        return None
+    # the program's gc hook appends a span to the ring on any thread; a
+    # collection while the ring is copied mutates it mid-iteration
+    enabled = gc.isenabled()
+    gc.disable()
+    try:
+        return [tuple(s) for s in read()]
+    finally:
+        if enabled:
+            gc.enable()
 
 
 def align(trace, records) -> Optional[Aligned]:

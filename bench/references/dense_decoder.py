@@ -3,9 +3,10 @@
 Straight ``jax.numpy`` at the highest matmul precision, with no kernel, no
 cache, no quantization and no batching: RMSNorm, rotary position
 embedding with the half rotation, causal grouped-query attention, a gated
-SiLU MLP and a tied LM head, as the published Llama and Qwen2 code
-describes them. It reads the weights in the dense layout of
-``bench/model.py`` and imports nothing of the program.
+SiLU MLP and an LM head tied to the embedding or of its own, as the
+published Llama and Qwen2 code describes them. It reads the weights in the
+layout of ``bench/layouts/dense_decoder.py`` and imports nothing of the
+program.
 
 ``logits_at`` runs one sequence (the cushion prefix, the prompt and the
 served tokens) and returns the logits at the rows asked for. Sequences are
@@ -84,13 +85,16 @@ def _logits_at(weights, tokens, rows, n_items):
         x, _ = jax.lax.scan(body, x, _flat(weights))
         x = _rms(x[rows], weights["ln_f"]["g"].astype(jnp.float32),
                  n["eps"])
+        if "head" in weights:
+            return x @ weights["head"]["w"].astype(jnp.float32)
         return x @ emb.T
 
 
 def sizes(cfg: dict) -> tuple:
     d, H = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
     return (("H", H), ("K", int(cfg["num_key_value_heads"])),
-            ("hd", d // H), ("eps", float(cfg["rms_norm_eps"])),
+            ("hd", int(cfg.get("head_dim") or d // H)),
+            ("eps", float(cfg["rms_norm_eps"])),
             ("theta", float(cfg["rope_theta"])))
 
 

@@ -4,8 +4,10 @@
     python3 bench/run.py --workload NAME --seed N --seconds S --trace 0|1
 
 A cell names a configuration (``bench/configs/<config>.json``) and a traffic
-mix (``bench/traffic/<traffic>.json``); each metric has a reader of its own
-(``bench/metrics/<metric>.py``), found by name. With ``--trace 0`` the result
+mix (``bench/traffic/<traffic>.json``); the configuration names its weight
+layout (``bench/layouts/<layout>.py``) and its plain reference
+(``bench/references/<reference>.py``), and each metric has a reader of its
+own (``bench/metrics/<metric>.py``), all found by name. With ``--trace 0`` the result
 carries the cell's end-to-end metrics, with ``--trace 1`` its per-layer
 metrics, read from a profiler trace of the window.
 

@@ -5,6 +5,14 @@ The tiny configuration (``testdata/configs/tiny-dense.json``) has the
 served models' layout at d=128; its widest-gap limit, 0.15 logits, lies
 between the readings of its sound runs (0.040-0.069 over seeds 1-6) and of
 its W4A8 control (0.355-0.500), both measured here on the CPU.
+
+``testdata/configs/tiny-untied.json`` brings its layout as a file that only
+the tests know (``testdata/layouts/folded_decoder.py``): an LM head of its
+own and head_dim 64 at d=128 over 4 heads. Its limit, 0.7 logits, lies
+between its sound runs (0.185-0.371 over seeds 1-12) and its W4A8 control
+(1.273-2.662 over the same seeds), measured here on the CPU; served while
+its reference reads an RMSNorm eps of 1e-3 (the embeddings' mean square is
+4e-4), it read 3.98-4.26 over seeds 1-3.
 """
 import json
 import os
@@ -25,13 +33,21 @@ with open(os.path.join(TD, "bench.json")) as f:
 SECONDS = 1.5
 
 
-def execute(seed, fault=None):
-    code, res = R.execute(SPEC, "tiny.mix", seed, SECONDS, False,
+def execute(seed, fault=None, workload="tiny.mix",
+            config_dir=os.path.join(TD, "configs")):
+    code, res = R.execute(SPEC, workload, seed, SECONDS, False,
                           require_chip=False, fault=fault,
-                          config_dir=os.path.join(TD, "configs"),
+                          config_dir=config_dir,
                           traffic_dir=os.path.join(TD, "traffic"))
     assert code == 0
     return res
+
+
+@pytest.fixture
+def testdata_layouts(monkeypatch):
+    """Layouts are looked for in testdata/layouts too."""
+    monkeypatch.setattr(M, "LAYOUT_DIRS",
+                        M.LAYOUT_DIRS + (os.path.join(TD, "layouts"),))
 
 
 def test_sound_run_is_correct(capsys):
@@ -91,8 +107,31 @@ def test_broken_path_is_not_correct(fault):
     assert check["value"] > check["limit"]
 
 
-def test_control_fails_the_limit():
-    cfg = M.load_config("tiny-dense", os.path.join(TD, "configs"))
+def test_layout_in_new_files_is_correct(testdata_layouts):
+    res = execute(3, workload="tiny-untied.mix")
+    assert res["correct"] is True
+    assert res["failed"] == 0
+
+
+def test_ignored_norm_eps_is_not_correct(testdata_layouts, tmp_path,
+                                         monkeypatch):
+    """The configuration states an RMSNorm eps of 1e-3 and the program is
+    built with 1e-6: the reference reads the configuration's."""
+    cfg = M.load_config("tiny-untied", os.path.join(TD, "configs"))
+    cfg["rms_norm_eps"] = 1e-3
+    (tmp_path / "tiny-untied.json").write_text(json.dumps(cfg))
+    layout = M.load_layout(cfg)
+    monkeypatch.setattr(M, "program_config", lambda c: layout.program_config(
+        dict(c, rms_norm_eps=1e-6)))
+    res = execute(3, workload="tiny-untied.mix", config_dir=str(tmp_path))
+    assert res["correct"] is False
+    check = res["checks"]["widest_gap"]
+    assert check["value"] > check["limit"]
+
+
+@pytest.mark.parametrize("name", ["tiny-dense", "tiny-untied"])
+def test_control_fails_the_limit(name, testdata_layouts):
+    cfg = M.load_config(name, os.path.join(TD, "configs"))
     mix = TF.load_mix("tiny-mix", os.path.join(TD, "traffic"))
     o = CL.run(cfg, mix, 4, SECONDS, control=C.w4a8_control)
     limit = cfg["correct"]["widest_gap_limit"]

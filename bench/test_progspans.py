@@ -134,3 +134,23 @@ def test_one_edge_moved_still_aligns(trace):
     assert a is not None and a.residual_ns == 2000
     assert progspans.align(trace, records(shift=(1, 2_000_000, 2_000_000))) \
         is None
+
+
+def test_ring_is_read_with_the_collector_held(monkeypatch):
+    """The program's gc hook appends to the ring it keeps: a collection
+    while the ring is copied would raise "deque mutated during iteration"
+    (one traced decode run of 2 on a TPU v5e did). The copy is made with
+    the collector off, and the collector is on again afterwards."""
+    import gc
+    from repro import monitoring
+
+    def read():
+        for r in records():
+            if gc.isenabled():
+                raise RuntimeError("deque mutated during iteration")
+            yield r
+
+    monkeypatch.setattr(monitoring, "spans", read)
+    assert gc.isenabled()
+    assert progspans.program_spans() == records()
+    assert gc.isenabled()

@@ -26,6 +26,14 @@ def test_every_cell_names_existing_files():
 
 
 @pytest.mark.parametrize("c", SPEC["configs"], ids=lambda c: c["name"])
+def test_config_names_a_layout(c):
+    layout = M.load_layout(M.load_config(c["name"]))
+    for fn in ("program_config", "make_weights", "linear_shapes",
+               "matmul_shapes"):
+        assert callable(getattr(layout, fn)), fn
+
+
+@pytest.mark.parametrize("c", SPEC["configs"], ids=lambda c: c["name"])
 def test_config_entry_matches_its_file(c):
     path = os.path.join(R.ROOT, c["file"])
     with open(path) as f:
