@@ -29,9 +29,9 @@ class MoEConfig:
     # Router options
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
-    # GShard capacity factor; tokens over capacity are dropped (pass through
-    # the residual). Set high for dropless behaviour in tests.
-    capacity_factor: float = 1.25
+    # renormalise the top-k router weights to sum to 1 (Mixtral, Arctic);
+    # OLMoE weights its experts by the raw softmax probabilities
+    norm_topk_prob: bool = True
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +83,10 @@ class ModelConfig:
     qkv_bias: bool = False           # qwen-style
     tie_embeddings: bool = False
     norm: str = "rmsnorm"            # "rmsnorm" | "layernorm"
+    norm_eps: float = 1e-6           # RMSNorm eps (layernorm keeps 1e-5)
+    # RMSNorm over the whole q and k projections, before the head split and
+    # rope (OLMoE's q_norm / k_norm)
+    qk_norm: bool = False
     act: str = "silu"                # "silu" (gated) | "gelu" (dense ff)
     gated_mlp: bool = True
     rope_theta: float = 10000.0
@@ -237,8 +241,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         kw["moe"] = dataclasses.replace(
             cfg.moe, num_experts=min(cfg.moe.num_experts, 8),
             top_k=min(cfg.moe.top_k, 2),
-            dense_residual_ff=128 if cfg.moe.dense_residual_ff else 0,
-            capacity_factor=64.0)  # dropless at smoke scale
+            dense_residual_ff=128 if cfg.moe.dense_residual_ff else 0)
     if cfg.hybrid is not None:
         kw["n_layers"] = cfg.hybrid.period  # one full period
     if cfg.encdec is not None:

@@ -23,6 +23,7 @@ through the model forward.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -479,6 +480,15 @@ def true_int_dot(x: Array, w: Array, cfg: QuantConfig,
     Weights are quantized on the fly (constant-folds under jit when ``w``
     is a weight); ``prequantized_int_dot`` is the int8-resident variant."""
     wq, s_w = weight_quant_int(w, cfg)
+    xq, s_x, z_x = _int8_acts(x, cfg, site)
+    colsum = jnp.sum(wq.astype(jnp.int32), axis=0)
+    return _int8_matmul(xq, wq, s_x, z_x, s_w, colsum, x.dtype, row_parallel)
+
+
+def _int8_acts(x: Array, cfg: QuantConfig, site: Optional[SiteScale]
+               ) -> Tuple[Array, Array, Array]:
+    """Activations as int8 for the int matmuls: (x_int8, s_x, z_x), the
+    site's static range under ``pt_static``, else one from x itself."""
     if cfg.mode == "pt_static":
         assert site is not None
         s_x, z_x = site.scale, site.zero
@@ -492,9 +502,7 @@ def true_int_dot(x: Array, w: Array, cfg: QuantConfig,
         off = 2 ** (cfg.a_bits - 1)
         xq = xq - off
         z_x = z_x - off
-    xq = xq.astype(jnp.int8)
-    colsum = jnp.sum(wq.astype(jnp.int32), axis=0)
-    return _int8_matmul(xq, wq, s_x, z_x, s_w, colsum, x.dtype, row_parallel)
+    return xq.astype(jnp.int8), s_x, z_x
 
 
 def prequantized_int_dot(x: Array, w: Dict[str, Array], cfg: QuantConfig,
@@ -516,18 +524,152 @@ def prequantized_int_dot(x: Array, w: Dict[str, Array], cfg: QuantConfig,
             "prequantized (int8-resident) weights serve the pt_static "
             "deployment path only and need calibrated site scales; got "
             f"mode={cfg.mode!r}, site={'set' if site is not None else None}")
-    s_x, z_x = site.scale, site.zero
-    xq = quantize(x, s_x, z_x, cfg.a_bits, cfg.symmetric_a)
-    if not cfg.symmetric_a:
-        off = 2 ** (cfg.a_bits - 1)
-        xq = xq - off
-        z_x = z_x - off
-    xq = xq.astype(jnp.int8)
+    xq, s_x, z_x = _int8_acts(x, cfg, site)
     if "w_packed" in w:
         return _int4_matmul(xq, w["w_packed"], s_x, z_x, w["w_scale"],
                             w["colsum"], x.dtype, row_parallel)
     return _int8_matmul(xq, w["w_int"], s_x, z_x, w["w_scale"],
                         w["colsum"], x.dtype, row_parallel)
+
+
+# ---------------------------------------------------------------------------
+# Grouped (expert) matmuls: rows sorted by expert
+# ---------------------------------------------------------------------------
+
+def expert_quant_int(w: Array, cfg: QuantConfig) -> Tuple[Array, Array]:
+    """Symmetric int8 weights of an (..., E, K, N) expert stack with one
+    scale per (expert, output column): (w_int8, scale (..., E, N)). The
+    column scales ride the grouped kernel's epilogue, so every expert
+    keeps its own range."""
+    wf = w.astype(jnp.float32)
+    amax = jnp.max(jnp.abs(wf), axis=-2)
+    scale, _ = params_from_minmax(-amax, amax, cfg.w_bits, True)
+    wq = quantize(wf, scale[..., None, :], jnp.zeros(()), cfg.w_bits, True)
+    return wq.astype(jnp.int8), scale
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def prequantize_experts(w: Array, cfg: QuantConfig,
+                        weight_bits: int = 8) -> Any:
+    """An (..., E, K, N) expert stack in its resident serving form:
+    {"w_int" int8, "w_scale" (..., E, N), "colsum" (..., E, N) int32}.
+    At ``weight_bits=4`` (the W4A8 control) the experts keep float storage
+    holding the group-wise 4-bit values (``weight_fake_quant``): the
+    grouped path multiplies them as it multiplies any float expert. One
+    jitted call per stack, so no float32 copy of a stack is made whole."""
+    if weight_bits == 4:
+        return weight_fake_quant(w, dataclasses.replace(cfg, w_bits=4))
+    wq, scale = expert_quant_int(w, cfg)
+    return {"w_int": wq, "w_scale": scale,
+            "colsum": jnp.sum(wq.astype(jnp.int32), axis=-2)}
+
+
+def _row_experts(group_sizes: Array, M: int) -> Array:
+    """The expert of each of M rows sorted by expert (E for rows past the
+    groups)."""
+    return jnp.searchsorted(jnp.cumsum(group_sizes), jnp.arange(M),
+                            side="right")
+
+
+def _grouped_dense(x: Array, w: Array, group_sizes: Array,
+                   preferred_element_type=None) -> Array:
+    """Rows sorted by expert times their experts' weights in plain jnp:
+    one product per expert over every row, each row keeping its own
+    expert's. E times the work of a grouped kernel, but it runs anywhere,
+    under vmap and under autodiff (the calibration, the search and the
+    prefix tuning), and adds only exact zeros to each row's product."""
+    expert = _row_experts(group_sizes, x.shape[0])
+
+    def body(acc, ew):
+        e, we = ew
+        y = jnp.matmul(x, we, preferred_element_type=preferred_element_type)
+        return acc + jnp.where((expert == e)[:, None], y, 0).astype(
+            acc.dtype), None
+
+    dt = preferred_element_type or jnp.result_type(x, w)
+    acc, _ = jax.lax.scan(body, jnp.zeros((x.shape[0], w.shape[-1]), dt),
+                          (jnp.arange(w.shape[0]), w))
+    return acc
+
+
+def _int8_gmm(xq: Array, w: Dict[str, Array], group_sizes: Array, s_x,
+              z_x, out_dtype) -> Array:
+    """int8 rows sorted by expert times their experts' int8 weights, with
+    the per-column epilogue  (X_g @ W_g - z colsum_g) s_x s_w[g].
+
+    ``w`` holds (E, K, N) arrays, or the (L, E, K, N) stack of every layer
+    with the layer to read under "layer". On TPU (or with
+    REPRO_W8A8_KERNEL=pallas) the Pallas ``w8a8_gmm`` kernel runs it,
+    reading only the experts that have rows, and only the given layer of a
+    stack; elsewhere ``_grouped_dense`` does, integer-exact. Under a tp
+    mesh the kernel runs whole on every device."""
+    w_int, s_w, colsum = w["w_int"], w["w_scale"], w["colsum"]
+    layer = w.get("layer")
+    M, E = xq.shape[0], group_sizes.shape[0]
+    if _use_w8a8_kernel() and jnp.ndim(s_x) == 0:
+        from repro.kernels.w8a8_gmm import w8a8_gmm
+        run = functools.partial(
+            w8a8_gmm, tm=32 if M <= 32 * E else 128,
+            tn=_tile(w_int.shape[-1], 1024),
+            interpret=jax.default_backend() != "tpu")
+        args = (xq, w_int, group_sizes, jnp.asarray(s_x, jnp.float32),
+                jnp.asarray(z_x, jnp.float32), s_w, colsum,
+                jnp.int32(0) if layer is None else layer)
+        mesh = _tp_mesh()
+        if mesh is None:
+            out = run(*args)
+        else:
+            from jax.sharding import PartitionSpec as P
+
+            from repro.distributed.sharding import shard_map
+            out = shard_map(run, mesh, in_specs=(P(),) * len(args),
+                            out_specs=P())(*args)
+        return out.astype(out_dtype)
+    if layer is not None:
+        w_int, s_w, colsum = (a[layer] for a in (w_int, s_w, colsum))
+    if jax.default_backend() != "tpu":
+        # XLA:CPU has no int8 GEMM: f32 products in contraction chunks of
+        # _F32_EXACT_K stay integer-exact (see _int_product_f32_exact)
+        K = xq.shape[-1]
+        xf, wf = xq.astype(jnp.float32), w_int.astype(jnp.float32)
+        acc = sum(_grouped_dense(xf[:, k0:k0 + _F32_EXACT_K],
+                                 wf[:, k0:k0 + _F32_EXACT_K],
+                                 group_sizes).astype(jnp.int32)
+                  for k0 in range(0, K, _F32_EXACT_K))
+    else:
+        acc = _grouped_dense(xq, w_int, group_sizes, jnp.int32)
+    expert = jnp.minimum(_row_experts(group_sizes, M), E - 1)
+    acc = acc.astype(jnp.float32) - jnp.asarray(z_x, jnp.float32) \
+        * colsum[expert].astype(jnp.float32)
+    return (acc * (jnp.asarray(s_x, jnp.float32)
+                   * s_w[expert].astype(jnp.float32))).astype(out_dtype)
+
+
+def grouped_qdot(x: Array, w: Any, group_sizes: Array, cfg: QuantConfig,
+                 site: Optional[SiteScale] = None) -> Array:
+    """Quantized grouped product, ``qdot``'s counterpart for an expert
+    stack: x holds rows sorted by expert, ``group_sizes`` (E,) their count
+    per expert, and row r of expert g comes out as q(x_r) @ q(W_g). ``w``
+    is an (E, K, N) stack or its prequantized dict
+    (``prequantize_experts``), whose arrays may be the (L, E, K, N) stack
+    of every layer with the layer under "layer"."""
+    if isinstance(w, dict):
+        if cfg.mode != "pt_static" or site is None:
+            raise ValueError(
+                "prequantized (int8-resident) experts serve the pt_static "
+                "deployment path only and need calibrated site scales; got "
+                f"mode={cfg.mode!r}, site={'set' if site is not None else None}")
+        xq, s_x, z_x = _int8_acts(x, cfg, site)
+        return _int8_gmm(xq, w, group_sizes, s_x, z_x, x.dtype)
+    if cfg.mode == "none":
+        return _grouped_dense(x, w, group_sizes)
+    if cfg.true_int8 and cfg.a_bits == 8 and cfg.w_bits == 8:
+        xq, s_x, z_x = _int8_acts(x, cfg, site)
+        return _int8_gmm(xq, prequantize_experts(w, cfg), group_sizes, s_x,
+                         z_x, x.dtype)
+    xq = act_fake_quant(x, cfg, site.scale if site is not None else None,
+                        site.zero if site is not None else None)
+    return _grouped_dense(xq, weight_fake_quant(w, cfg), group_sizes)
 
 
 def prequantize(w: Array, cfg: QuantConfig,
@@ -557,25 +699,30 @@ def prequantize(w: Array, cfg: QuantConfig,
 
 _PREQUANT_KEYS = ("wqkv", "wo", "w_up", "w_gate", "w_down", "w_in", "w_out",
                   "w_proj")
+# an MoE layer's expert stacks, read by ``grouped_qdot``
+EXPERT_KEYS = ("w_up", "w_gate", "w_down")
 
 
 def prequantize_tree(params: Any, cfg: QuantConfig,
                      min_ndim: int = 2, weight_bits: int = 8) -> Any:
     """Replace qdot-consumed weight matrices with int-resident Quantized
     dicts (int8 ``w_int`` or, with ``weight_bits=4``, nibble-packed
-    ``w_packed``). Only keys consumed via `qlinear`/`qdot` are converted
-    (MoE expert/gate projections consumed by raw einsums — and the Arctic
-    dense residual branch living under the same ``moe`` subtree — keep fp);
-    embeddings stay fp (gather lookups). Hybrid period params nest their
-    sublayers in lists; those are descended too."""
+    ``w_packed``), and an MoE layer's expert stacks with their grouped form
+    (``prequantize_experts``: int8 per (expert, output column)). The router
+    stays fp, as do embeddings (gather lookups); the Arctic dense residual
+    branch under ``moe/residual`` is a dense MLP and converts like one.
+    Hybrid period params nest their sublayers in lists; those are descended
+    too."""
     if weight_bits not in (8, 4):
         raise ValueError(f"weight_bits must be 8 or 4, got {weight_bits}")
 
     def eligible(k, v, path):
         if not (hasattr(v, "ndim") and v.ndim >= min_ndim):
             return False
-        if "embed" in path or "moe" in path:
+        if "embed" in path:
             return False
+        if path and path[-1] == "moe":
+            return k in EXPERT_KEYS
         if k in _PREQUANT_KEYS:
             return True
         return k == "w" and path and path[-1] == "head"
@@ -600,7 +747,8 @@ def prequantize_tree(params: Any, cfg: QuantConfig,
                 out[k] = [visit(e, path + (k, i)) if isinstance(e, dict)
                           else e for i, e in enumerate(v)]
             elif eligible(k, v, path):
-                out[k] = convert(v)
+                out[k] = (prequantize_experts(v, cfg, weight_bits)
+                          if path and path[-1] == "moe" else convert(v))
             else:
                 out[k] = v
         return out
@@ -632,8 +780,10 @@ def qdot(x: Array, w: Any, cfg: QuantConfig,
 # ---------------------------------------------------------------------------
 
 def site_qerr(x: Array, cfg: QuantConfig, site: Optional[SiteScale],
-              n_skip: int = 0) -> Array:
+              n_skip: int = 0, valid: Optional[Array] = None) -> Array:
     """||X - q(X)||^2 over the token part (positions >= n_skip along axis -2).
+    ``valid`` (rows of a 2-D x) keeps only the rows where it is True: the
+    rows of an MoE's sorted expert buffer whose tokens lie past n_skip.
 
     For dynamic modes the scale is derived from the same (token-part) tensor,
     mirroring deployment; for static mode the calibrated scale is used.
@@ -646,25 +796,38 @@ def site_qerr(x: Array, cfg: QuantConfig, site: Optional[SiteScale],
         scale, zero = site.scale, site.zero
     else:
         per_token = cfg.mode == "ptoken_dynamic"
-        mn, mx = act_minmax(jax.lax.stop_gradient(x), per_token)
+        xs = jax.lax.stop_gradient(x)
+        if valid is None or per_token:
+            mn, mx = act_minmax(xs, per_token)
+        else:
+            st = site_stats(xs, valid=valid)
+            mn, mx = st["amin"], st["amax"]
         scale, zero = params_from_minmax(mn, mx, cfg.a_bits, cfg.symmetric_a)
     scale = jax.lax.stop_gradient(scale)
     zero = jax.lax.stop_gradient(zero)
     xq = dequantize(quantize(x, scale, zero, cfg.a_bits, cfg.symmetric_a),
                     scale, zero)
-    return jnp.sum(jnp.square((x - xq).astype(jnp.float32)))
+    err = jnp.square((x - xq).astype(jnp.float32))
+    if valid is not None:
+        err = jnp.where(valid[:, None], err, 0.0)
+    return jnp.sum(err)
 
 
-def site_stats(x: Array, n_skip: int = 0) -> Dict[str, Array]:
-    """Reduced statistics for calibration & Table-5-style analysis."""
+def site_stats(x: Array, n_skip: int = 0,
+               valid: Optional[Array] = None) -> Dict[str, Array]:
+    """Reduced statistics for calibration & Table-5-style analysis, over
+    the rows ``valid`` keeps where it is given (see ``site_qerr``)."""
     if n_skip:
         x = x[..., n_skip:, :]
     xf = x.astype(jnp.float32)
-    return {
-        "amin": jnp.min(xf),
-        "amax": jnp.max(xf),
-        "absmax_ch": jnp.max(jnp.abs(xf), axis=tuple(range(x.ndim - 1))),
-    }
+    axes = tuple(range(x.ndim - 1))
+    if valid is None:
+        return {"amin": jnp.min(xf), "amax": jnp.max(xf),
+                "absmax_ch": jnp.max(jnp.abs(xf), axis=axes)}
+    v = valid[:, None]
+    return {"amin": jnp.min(jnp.where(v, xf, jnp.inf)),
+            "amax": jnp.max(jnp.where(v, xf, -jnp.inf)),
+            "absmax_ch": jnp.max(jnp.where(v, jnp.abs(xf), 0.0), axis=axes)}
 
 
 def scales_from_stats(stats: Any, cfg: QuantConfig) -> Any:

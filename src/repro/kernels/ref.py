@@ -20,6 +20,30 @@ def w8a8_matmul_ref(x_int: jax.Array, w_int: jax.Array, s_x: jax.Array,
     return acc * (s_x * s_w)
 
 
+def w8a8_gmm_ref(x_int: jax.Array, w_int: jax.Array,
+                 group_sizes: jax.Array, s_x, z_x, w_scale: jax.Array,
+                 colsum: jax.Array | None = None) -> jax.Array:
+    """Oracle for the grouped kernel: every row times every expert's int8
+    weight in int32, each row keeping its own expert's product, then the
+    epilogue with that expert's column sums and scales. x_int: (M,K) int8
+    sorted by expert; w_int: (E,K,N) int8; group_sizes: (E,); w_scale:
+    (E,N). Rows past sum(group_sizes) come out 0. Returns fp32 (M,N)."""
+    M = x_int.shape[0]
+    E = w_int.shape[0]
+    expert = jnp.searchsorted(jnp.cumsum(group_sizes), jnp.arange(M),
+                              side="right")
+    every = jax.lax.dot_general(
+        x_int, w_int, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)                   # (M, E, N)
+    own = jnp.minimum(expert, E - 1)
+    acc = every[jnp.arange(M), own].astype(jnp.float32)
+    if colsum is None:
+        colsum = jnp.sum(w_int.astype(jnp.int32), axis=1)
+    out = (acc - z_x * colsum[own].astype(jnp.float32)) \
+        * (s_x * w_scale[own].astype(jnp.float32))
+    return jnp.where((expert < E)[:, None], out, 0.0)
+
+
 def w4a8_matmul_ref(x_int: jax.Array, w_packed: jax.Array, s_x: jax.Array,
                     z_x: jax.Array, s_w: jax.Array,
                     group_size: int) -> jax.Array:

@@ -64,7 +64,7 @@ def apply_norm(p: Params, x: Array, cfg: ModelConfig) -> Array:
         y = y * p["g"].astype(jnp.float32) + p["b"].astype(jnp.float32)
     else:
         ms = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(ms + 1e-6) * p["g"].astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(ms + cfg.norm_eps) * p["g"].astype(jnp.float32)
     return y.astype(x.dtype)
 
 
@@ -169,12 +169,22 @@ def attn_init(key, cfg: ModelConfig) -> Params:
     }
     if cfg.qkv_bias:
         p["bqkv"] = jnp.zeros(((H + 2 * K) * hd,), dt)
+    if cfg.qk_norm:
+        p["q_norm"] = {"g": jnp.ones((H * hd,), dt)}
+        p["k_norm"] = {"g": jnp.ones((K * hd,), dt)}
     return p
 
 
-def _split_qkv(qkv: Array, cfg: ModelConfig) -> Tuple[Array, Array, Array]:
+def _split_qkv(qkv: Array, cfg: ModelConfig,
+               p: Optional[Params] = None) -> Tuple[Array, Array, Array]:
+    """Heads of the fused projection; with ``cfg.qk_norm`` the whole q and
+    k projections are RMS-normalized (gains ``p["q_norm"]``,
+    ``p["k_norm"]``) before the head split."""
     hd, H, K = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
     q, k, v = jnp.split(qkv, [H * hd, (H + K) * hd], axis=-1)
+    if cfg.qk_norm:
+        q = apply_norm(p["q_norm"], q, cfg)
+        k = apply_norm(p["k_norm"], k, cfg)
     q = q.reshape(*q.shape[:-1], H, hd)
     k = k.reshape(*k.shape[:-1], K, hd)
     v = v.reshape(*v.shape[:-1], K, hd)
@@ -321,7 +331,7 @@ def attention_full(p: Params, x: Array, cfg: ModelConfig, qcfg: QuantConfig,
     B, S, _ = x.shape
     qkv = qlinear(x, p["wqkv"], p.get("bqkv"), qcfg, scales, "qkv", taps,
                   n_skip)
-    q, k, v = _split_qkv(qkv, cfg)
+    q, k, v = _split_qkv(qkv, cfg, p)
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
@@ -438,7 +448,7 @@ def attention_decode_kv(p: Params, x: Array, kv: Params, pos: Array,
     """
     B = x.shape[0]
     qkv = qlinear(x, p["wqkv"], p.get("bqkv"), qcfg, scales, "qkv", taps)
-    q, k, v = _split_qkv(qkv, cfg)
+    q, k, v = _split_qkv(qkv, cfg, p)
     per_row = jnp.ndim(pos) == 1
     posv = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
     cos, sin = rope_cos_sin(posv[:, None], cfg.head_dim, cfg.rope_theta)

@@ -145,7 +145,8 @@ def _period_apply(pp: Params, x: Array, cfg: ModelConfig, qcfg: QuantConfig,
         x = x + o
         hn = C.apply_norm(sub["ln2"], x, cfg)
         if mlp == "moe":
-            y, lb = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, taps, n_skip)
+            y, lb, _ = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, taps,
+                                     n_skip)
             lb_total = lb_total + lb
         else:
             y = C.apply_mlp(sub["mlp"], hn, cfg, qcfg, lsc, taps, n_skip)
@@ -345,7 +346,7 @@ def prefill(params: Params, tokens: Array, cache: Params, cfg: ModelConfig,
             h = h + o
             hn = C.apply_norm(sub["ln2"], h, cfg)
             if mlp == "moe":
-                y, _ = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, None)
+                y, _, _ = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, None)
             else:
                 y = C.apply_mlp(sub["mlp"], hn, cfg, qcfg, lsc, None)
             h = constrain(h + y, "B")
@@ -435,7 +436,7 @@ def decode_step(params: Params, token: Array, pos: Array, cache: Params,
             h = h + o
             hn = C.apply_norm(sub["ln2"], h, cfg)
             if mlp == "moe":
-                y, _ = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, None)
+                y, _, _ = MOE.apply_moe(sub["moe"], hn, cfg, qcfg, lsc, None)
             else:
                 y = C.apply_mlp(sub["mlp"], hn, cfg, qcfg, lsc, None)
             h = h + y

@@ -1,14 +1,21 @@
-"""Mixture-of-Experts decoder (olmoe top-8; arctic 128e top-2 + dense
-residual branch).
+"""Mixture-of-Experts decoder (olmoe: 64 experts, top-8; arctic: 128
+experts, top-2, with a dense residual branch).
 
-Expert dispatch is GShard/Switch-style capacity-based dense dispatch — the
-canonical partitionable formulation under GSPMD: experts shard on `model`,
-tokens on batch axes; the dispatch einsums lower to all-to-all-like
-collectives. Capacity factor 1.25, dropped tokens pass through the residual.
+Dispatch is dropless and sorted: each token's top-k (token, expert) rows
+are sorted by expert, every expert multiplies its contiguous run of rows in
+one grouped matmul (``core.quantization.grouped_qdot``: the Pallas
+``w8a8_gmm`` kernel for int8 experts on TPU, a plain jnp product
+elsewhere), and the rows go back to their tokens, summed with their router
+weights. No token is dropped and no buffer holds padding rows, in prefill,
+decode or calibration alike.
+
+``prefill`` and ``decode_step`` take ``route_counts=True`` and then also
+return int32 [rows routed, experts reached, rows dropped], summed over the
+layers (``ROUTE_COUNTS``): the serving engine reads them with the step's
+tokens.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -27,13 +34,14 @@ Params = Dict[str, Any]
 SITES = C.ATTN_SITES + ("mlp_in", "down")
 
 # Attention-KV-only prefix artifact -> eligible for the greedy-search
-# KV-reuse scoring fast path (ModelAPI.score_candidates). Note the scoring
-# contract for MoE: expert capacity is derived from the *scored* sequence
-# ([candidate; sample]), and the "down" site qerr covers only that
-# sequence's expert traffic — prefix tokens never re-enter the experts,
-# matching deployment (the reference full-forward scorer routes prefix
-# tokens through the experts as a side effect of recomputing them).
+# KV-reuse scoring fast path (ModelAPI.score_candidates). Dispatch is per
+# token, so a token's expert output does not depend on the other tokens of
+# the sequence: scoring [candidate; sample] against the cached prefix KV
+# gives the sample's tokens the expert outputs a full forward of
+# [prefix; candidate; sample] gives them, and the "down" site, like every
+# other, leaves out the first n_skip positions of each sequence.
 SUPPORTS_PREFIX_KV_SCORING = True
+ROUTE_COUNTS = True
 
 
 def moe_init(key, cfg: ModelConfig) -> Params:
@@ -54,74 +62,99 @@ def moe_init(key, cfg: ModelConfig) -> Params:
     return p
 
 
-def capacity(seq: int, cfg: ModelConfig) -> int:
-    moe = cfg.moe
-    c = int(np.ceil(seq * moe.top_k / moe.num_experts * moe.capacity_factor))
-    c = min(c, seq * moe.top_k)
-    return max(4, int(np.ceil(c / 4)) * 4)
+def route(p: Params, x: Array, cfg: ModelConfig
+          ) -> Tuple[Array, Array, Array]:
+    """x: (T, D) -> (top-k weights (T, K) f32, their experts (T, K) int32,
+    router probabilities (T, E)). The weights are the softmax
+    probabilities, renormalized over the top k where ``norm_topk_prob``."""
+    probs = jax.nn.softmax(x.astype(jnp.float32) @ p["router"], axis=-1)
+    top_w, top_idx = jax.lax.top_k(probs, cfg.moe.top_k)
+    if cfg.moe.norm_topk_prob:
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    return top_w, top_idx, probs
 
 
 def apply_moe(p: Params, x: Array, cfg: ModelConfig, qcfg: QuantConfig,
               scales: Optional[Params], taps: Optional[Dict],
-              n_skip: int = 0) -> Tuple[Array, Array]:
-    """Returns (y, load_balance_loss)."""
+              n_skip: int = 0) -> Tuple[Array, Array, Array]:
+    """Returns (y, load_balance_loss, counts): counts is int32 [rows
+    routed, experts reached, rows dropped]. The expert weights may be the
+    stacked view ``{**stack, "layer": i}`` the serving scans pass."""
     moe = cfg.moe
     B, S, D = x.shape
     E, K = moe.num_experts, moe.top_k
-    Cp = capacity(S, cfg)
+    xt = x.reshape(B * S, D)
+    top_w, top_idx, probs = route(p, xt, cfg)
 
-    gate_logits = x.astype(jnp.float32) @ p["router"]          # (B,S,E)
-    gate_logits = constrain(gate_logits, "B", None, "M")
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    top_w, top_idx = jax.lax.top_k(probs, K)                    # (B,S,K)
-    top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+    # rows t*K + k sorted by expert; group_sizes counts each expert's rows
+    flat = top_idx.reshape(-1)
+    onehot = flat[:, None] == jnp.arange(E, dtype=flat.dtype)[None, :]
+    group_sizes = jnp.sum(onehot, axis=0, dtype=jnp.int32)
+    order = jnp.argsort(flat, stable=True)
+    tok = order // K
+    back = jnp.argsort(order)
 
-    # Switch-style load-balance loss: E * sum_e mean(frac_e) * mean(prob_e)
-    onehot = jax.nn.one_hot(top_idx, E, dtype=jnp.float32)      # (B,S,K,E)
-    frac = jnp.mean(jnp.sum(onehot, axis=2), axis=(0, 1))       # (E,)
-    lb = E * jnp.sum(frac * jnp.mean(probs, axis=(0, 1)))
-
-    # capacity assignment: position of each (token, k) within its expert
-    flat = onehot.reshape(B, S * K, E)
-    pos = jnp.cumsum(flat, axis=1) - 1.0                         # (B,S*K,E)
-    keep = (pos < Cp) * flat
-    slot = jax.nn.one_hot(pos, Cp, dtype=jnp.float32) * keep[..., None]
-    disp = slot.reshape(B, S, K, E, Cp).astype(x.dtype)          # (B,S,K,E,C)
-    disp = constrain(disp, "B", None, None, "M", None)
-    comb = jnp.einsum("bsk,bskec->bsec", top_w.astype(x.dtype), disp)
-    disp_tok = jnp.sum(disp, axis=2)                             # (B,S,E,C)
+    # Switch-style load-balance loss: E * sum_e frac_e * mean(prob_e)
+    frac = group_sizes.astype(jnp.float32) / (B * S)
+    lb = E * jnp.sum(frac * jnp.mean(probs, axis=0))
 
     if taps is not None:
         taps["mlp_in"] = {
             "qerr": Q.site_qerr(x, qcfg, C.get_site(scales, "mlp_in"), n_skip),
             **Q.site_stats(x, n_skip)}
 
-    xin = jnp.einsum("bsec,bsd->ebcd", disp_tok, x)              # (E,B,C,D)
-    xin = constrain(xin, "M", "B", None, None)
     qs = C.get_site(scales, "mlp_in")
-    xq = Q.act_fake_quant(xin, qcfg, qs.scale if qs else None,
-                          qs.zero if qs else None)
-    up = jnp.einsum("ebcd,edf->ebcf", xq, Q.weight_fake_quant(p["w_up"], qcfg))
-    gate = jnp.einsum("ebcd,edf->ebcf", xq,
-                      Q.weight_fake_quant(p["w_gate"], qcfg))
+    xr = xt[tok]
+    up = Q.grouped_qdot(xr, p["w_up"], group_sizes, qcfg, qs)
+    gate = Q.grouped_qdot(xr, p["w_gate"], group_sizes, qcfg, qs)
     h = jax.nn.silu(gate) * up
-    h = constrain(h, "M", "B", None, None)
     if taps is not None:
-        taps["down"] = {
-            "qerr": Q.site_qerr(h, qcfg, C.get_site(scales, "down"), 0),
-            **Q.site_stats(h, 0)}
-    qs2 = C.get_site(scales, "down")
-    hq = Q.act_fake_quant(h, qcfg, qs2.scale if qs2 else None,
-                          qs2.zero if qs2 else None)
-    out = jnp.einsum("ebcf,efd->ebcd", hq,
-                     Q.weight_fake_quant(p["w_down"], qcfg))
-    y = jnp.einsum("bsec,ebcd->bsd", comb, out)
-    y = constrain(y, "B")
+        # every row is a real (token, expert) row; those of the first
+        # n_skip positions of each sequence are left out, as at every site
+        valid = (tok % S) >= n_skip if n_skip else None
+        site = C.get_site(scales, "down")
+        taps["down"] = {"qerr": Q.site_qerr(h, qcfg, site, valid=valid),
+                        **Q.site_stats(h, valid=valid)}
+    yr = Q.grouped_qdot(h, p["w_down"], group_sizes, qcfg,
+                        C.get_site(scales, "down"))
+    y = jnp.sum(yr[back].reshape(B * S, K, D).astype(jnp.float32)
+                * top_w[..., None], axis=1)
+    y = constrain(y.reshape(B, S, D).astype(x.dtype), "B")
 
     if "residual" in p:
         # Arctic: dense FFN branch in parallel with the MoE branch
         y = y + C.apply_mlp(p["residual"], x, cfg, qcfg, scales, None, n_skip)
-    return y, lb
+    rows = jnp.sum(group_sizes)
+    counts = jnp.stack([rows, jnp.sum(group_sizes > 0),
+                        B * S * K - rows]).astype(jnp.int32)
+    return y, lb, counts
+
+
+def serving_layers(layers: Params):
+    """The layer params a serving scan runs over, with every int8-resident
+    expert stack taken out and the layer index put in, and ``bind(lp)``,
+    which hands a layer's params its experts back as views of the whole
+    stacks, ``{**stack, "layer": i}``. The grouped kernel reads a layer's
+    experts out of the stack by index, so the scan never slices a layer's
+    experts out of it (a copy of every expert's weights, each layer, each
+    step). Float experts stay in the scanned params."""
+    moe = layers["moe"]
+    stacks = {k: v for k, v in moe.items()
+              if k in Q.EXPERT_KEYS and isinstance(v, dict)}
+    if not stacks:
+        return layers, lambda lp: lp
+    n_layers = jax.tree_util.tree_leaves(stacks)[0].shape[0]
+    xs = {**layers, "moe": {k: v for k, v in moe.items() if k not in stacks},
+          "layer": jnp.arange(n_layers, dtype=jnp.int32)}
+
+    def bind(lp):
+        lp = dict(lp)
+        i = lp.pop("layer")
+        lp["moe"] = {**lp["moe"],
+                     **{k: {**st, "layer": i} for k, st in stacks.items()}}
+        return lp
+
+    return xs, bind
 
 
 def layer_init(key, cfg: ModelConfig) -> Params:
@@ -171,7 +204,7 @@ def forward(params: Params, tokens: Array, cfg: ModelConfig,
                              prefix_valid=prefix_valid)
         h = h + a
         hn = C.apply_norm(lp["ln2"], h, cfg)
-        y, lb = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, taps, n_skip)
+        y, lb, _ = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, taps, n_skip)
         h = constrain(h + y, "B")
         return h, ((taps if collect else {}), lb)
 
@@ -205,7 +238,10 @@ def prefill(params: Params, tokens: Array, cache: Params, cfg: ModelConfig,
             cushion: Optional[Params] = None,
             prepend_embeds: Optional[Array] = None,
             remat: bool = False,
-            pos_offset: Optional[int] = None) -> Tuple[Array, Params, Array]:
+            pos_offset: Optional[int] = None,
+            route_counts: bool = False):
+    """(logits, cache, pos), and the routing counts last with
+    ``route_counts`` (see the module)."""
     x = C.embed_tokens(params, tokens, cfg)
     if prepend_embeds is not None:
         x = jnp.concatenate([prepend_embeds.astype(x.dtype), x], axis=1)
@@ -227,50 +263,59 @@ def prefill(params: Params, tokens: Array, cache: Params, cfg: ModelConfig,
                else _empty_prefix(cfg, x.dtype))
     positions = m + jnp.arange(S)
     lscales = C.resolve_scales(scales, SITES, cfg.n_layers, qcfg)
+    layers, bind = serving_layers(params["layers"])
 
     def body(h, xs):
         lp, lsc, lpre = xs
+        lp = bind(lp)
         hn = C.apply_norm(lp["ln1"], h, cfg)
         a, kv = C.attention_full(lp["attn"], hn, cfg, qcfg, lsc, None,
                                  positions, prefix_kv=lpre, causal=True,
                                  return_kv=True)
         h = h + a
         hn = C.apply_norm(lp["ln2"], h, cfg)
-        y, _ = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, None)
+        y, _, counts = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, None)
         h = constrain(h + y, "B")
-        return h, kv
+        return h, (kv, counts)
 
     if remat:
         body = jax.checkpoint(body)
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], lscales, pre))
+    x, ((ks, vs), counts) = jax.lax.scan(body, x, (layers, lscales, pre))
     cache = T.write_prompt_kv(cache, ks, vs, m)
     x = C.apply_norm(params["ln_f"], x, cfg)
     logits = C.lm_head(params, x[:, -1:], cfg, qcfg, scales, None)
-    return logits, cache, jnp.asarray(m + S, jnp.int32)
+    out = (logits, cache, jnp.asarray(m + S, jnp.int32))
+    return out + (jnp.sum(counts, axis=0),) if route_counts else out
 
 
 def decode_step(params: Params, token: Array, pos: Array, cache: Params,
                 cfg: ModelConfig, qcfg: QuantConfig, *,
-                scales: Optional[Params] = None) -> Tuple[Array, Params]:
+                scales: Optional[Params] = None, route_counts: bool = False):
     """One decode step; pos may be () shared or (B,) per-row (continuous
-    batching). Expert capacity/dispatch is per-row at S=1, so lock-step
-    decode of independent slots stays row-local."""
+    batching). Dispatch is per token, so lock-step decode of independent
+    slots stays row-local. Returns (logits, cache), and the routing counts
+    last with ``route_counts`` (see the module)."""
     x = C.embed_tokens(params, token[:, None], cfg)
     lscales = C.resolve_scales(scales, SITES, cfg.n_layers, qcfg)
+    layers, bind = serving_layers(params["layers"])
 
-    def block(lp, lsc, h, kvc):
+    def block(lp, lsc, carry, kvc):
+        h, total = carry
+        lp = bind(lp)
         hn = C.apply_norm(lp["ln1"], h, cfg)
         a, kvc = C.attention_decode_kv(lp["attn"], hn, kvc, pos, cfg, qcfg,
                                        lsc, None)
         h = h + a
         hn = C.apply_norm(lp["ln2"], h, cfg)
-        y, _ = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, None)
-        h = h + y
-        return h, kvc
+        y, _, counts = apply_moe(lp["moe"], hn, cfg, qcfg, lsc, None)
+        return (h + y, total + counts), kvc
 
-    x, cache = C.decode_layers(block, x, params["layers"], lscales, cache)
+    (x, counts), cache = C.decode_layers(
+        block, (x, jnp.zeros((3,), jnp.int32)), layers, lscales, cache)
     x = C.apply_norm(params["ln_f"], x, cfg)
     logits = C.lm_head(params, x, cfg, qcfg, scales, None)
+    if route_counts:
+        return logits[:, 0], cache, counts
     return logits[:, 0], cache
 
 

@@ -128,6 +128,12 @@ class ModelAPI:
         return tuple(getattr(self.mod, "PAGED_KV_LEAVES", ()))
 
     @property
+    def counts_routes(self) -> bool:
+        """True when prefill() and decode_step() take ``route_counts=True``
+        and then return the routing counters last (models/moe.py)."""
+        return bool(getattr(self.mod, "ROUTE_COUNTS", False))
+
+    @property
     def supports_chunked_prefill(self) -> bool:
         """True when prefill() accepts pos_offset to resume a partially
         staged B=1 fp row (chunked admission). Families whose prompt pass

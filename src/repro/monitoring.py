@@ -24,6 +24,9 @@ in any profiler trace, and one ``Span`` record in a process-wide ring of the
 last ``SPAN_RING`` spans, always on (a flight recorder). ``spans()`` returns
 the ring, ``span_summary()`` the per-name count and self-time percentiles.
 Python's generation-2 collections land in the same ring as ``python.gc``.
+``count(name, span_id, **values)`` records counters a call computed on the
+device, tied to the id of the span that made the call; ``counts()`` returns
+them.
 """
 from __future__ import annotations
 
@@ -140,6 +143,14 @@ class Span(NamedTuple):
     uid: Optional[int]
 
 
+class Count(NamedTuple):
+    """Counters of one call (``values``: name -> int), tied to the id of the
+    span open around the call."""
+    name: str
+    span_id: Optional[int]
+    values: dict
+
+
 class _OpenSpan:
     """A span while it is open (``SpanRecorder.span``). A plain class, not a
     generator, and a plain tuple in the ring: the recorder is always on."""
@@ -173,6 +184,7 @@ class SpanRecorder:
 
     def __init__(self, maxlen: int = SPAN_RING):
         self._ring: collections.deque = collections.deque(maxlen=maxlen)
+        self._counts: collections.deque = collections.deque(maxlen=maxlen)
         self._ids = itertools.count(1)
         self._local = threading.local()
         self._gc_open: Optional[tuple] = None
@@ -196,6 +208,13 @@ class SpanRecorder:
 
     def spans(self) -> List[Span]:
         return [Span._make(r) for r in self._ring]
+
+    def count(self, name: str, span_id: Optional[int], **values) -> None:
+        """Record one call's counters under the span that made it."""
+        self._counts.append((name, span_id, values))
+
+    def counts(self) -> List[Count]:
+        return [Count._make(c) for c in list(self._counts)]
 
     def summary(self) -> Dict[str, dict]:
         """Per span name: count, p50 and p99 self-time in ms (duration less
@@ -238,6 +257,8 @@ RECORDER = SpanRecorder()
 span = RECORDER.span
 spans = RECORDER.spans
 span_summary = RECORDER.summary
+count = RECORDER.count
+counts = RECORDER.counts
 gc.callbacks.append(RECORDER.on_gc)
 
 
@@ -247,8 +268,9 @@ def resident_weight_bytes(params) -> tuple:
     tree (core.quantization.prequantize_tree) holds its qdot-consumed
     matrices as int8 ``w_int`` leaves (1 byte/weight vs 2-4 for bf16/fp32)
     or nibble-packed int8 ``w_packed`` leaves (0.5 byte/weight, counted by
-    their packed size); everything else (embeddings, norms, scales, MoE
-    experts) counts as fp. Surfaced in ``ServeStats`` and printed by
+    their packed size), and its MoE expert stacks as int8 ``w_int`` leaves
+    too; everything else (embeddings, norms, scales, the router) counts as
+    fp. Surfaced in ``ServeStats`` and printed by
     launch/serve.py so the fp/W8A8/W4A8 A/B shows its memory side, not just
     TTFT/TPOT."""
     fp = i8 = i4 = 0
@@ -297,7 +319,12 @@ class ServeStats:
     ``prefix_hits`` / ``prefix_misses`` count prefix-cache lookups at
     admission, and ``positions_exhausted`` counts requests rejected because
     prompt+budget exceeds the pool's position capacity (the admission-time
-    check that replaces silently running out of positions mid-decode)."""
+    check that replaces silently running out of positions mid-decode).
+
+    MoE routing, summed over the layers of every decode step and blocking
+    admission prefill: ``routed_rows`` the (token, expert) rows routed,
+    ``experts_reached`` the experts that got a row, ``dropped_rows`` the
+    rows no expert took (0: dispatch is dropless)."""
     n_slots: int = 0
     steps: int = 0              # lock-step decode iterations
     live_slot_steps: int = 0    # sum over steps of live slots that step
@@ -320,6 +347,9 @@ class ServeStats:
     prefill_chunks: int = 0     # chunked-admission prefill chunks run
     deadline_prefill: int = 0   # streams aborted between chunks (deadline)
     page_table_syncs: int = 0   # host->device page-table mirrors (paged)
+    routed_rows: int = 0        # MoE (token, expert) rows routed
+    experts_reached: int = 0    # MoE experts that got a row, per layer
+    dropped_rows: int = 0       # MoE rows no expert took
 
     def reset(self) -> None:
         """Zero every per-run counter, keeping ``n_slots``, the resident
@@ -337,6 +367,7 @@ class ServeStats:
         self.positions_exhausted = 0
         self.prefill_chunks = self.deadline_prefill = 0
         self.page_table_syncs = 0
+        self.routed_rows = self.experts_reached = self.dropped_rows = 0
 
     def occupancy(self) -> float:
         return self.live_slot_steps / max(1, self.steps * self.n_slots)

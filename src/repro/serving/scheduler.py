@@ -140,8 +140,8 @@ from repro.configs.base import QuantConfig
 from repro.distributed import sharding as SH
 from repro.kernels.flash_decode import pack_pages, page_rows, unpack_pages
 from repro.models.registry import ModelAPI
-from repro.monitoring import (ServeStats, host_sync, resident_weight_bytes,
-                              span)
+from repro.monitoring import (ServeStats, count, host_sync,
+                              resident_weight_bytes, span)
 from repro.serving.engine import (bucket_steps, cache_seq_len,
                                   cushion_fingerprint, cushion_prefix_len,
                                   plan_quantization,
@@ -336,9 +336,15 @@ class ContinuousEngine:
         (self.stats.weight_bytes_fp, self.stats.weight_bytes_int8,
          self.stats.weight_bytes_int4) = resident_weight_bytes(self.params)
 
+        # families that route through experts (models/moe.py) return their
+        # routing counters from the blocking admission prefill and the
+        # decode step; they come back with the tokens each call reads
+        routes = {"route_counts": True} if api.counts_routes else {}
+
         # named step functions: the names label the compiled programs
         def prefill(p, b, c):
-            return api.prefill(p, b, c, qcfg, cushion=cushion, scales=scales)
+            return api.prefill(p, b, c, qcfg, cushion=cushion, scales=scales,
+                               **routes)
 
         # prefix-cache tail prefill: the cushion is a traced argument (the
         # shared stem extends it), one compile per (stem pages, tail) shape
@@ -419,13 +425,13 @@ class ContinuousEngine:
             # lives inside the cache rows / kc leaves)
             full = dict(cache)
             full.update(cu)
-            logits, full = api.decode_step(p, tok, pos, full, qcfg,
-                                           scales=scales)
+            logits, full, *counts = api.decode_step(p, tok, pos, full, qcfg,
+                                                    scales=scales, **routes)
             out_cache = {k: full[k] for k in cache}
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             nxt = jnp.where(live, nxt, 0)          # dead rows feed token 0
             pos = jnp.where(live, pos + 1, pos)    # freeze retired offsets
-            return nxt, pos, out_cache
+            return (nxt, pos, out_cache, *counts)
 
         # donate the pool cache: the old buffer is dead once self.cache is
         # rebound, and without donation every per-layer cache write would
@@ -651,8 +657,8 @@ class ContinuousEngine:
                 and not ({"patches", "frames"} & set(req.batch))
                 and req.batch["tokens"].shape[1] > self._chunk_budget()):
             return self._start_stream(req, free[0])
-        with span("serve.admit", uid=req.uid):
-            return self._admit_request(req, free[0])
+        with span("serve.admit", uid=req.uid) as sid:
+            return self._admit_request(req, free[0], sid)
 
     def _chunk_budget(self) -> int:
         """Per-step prefill token budget. Fixed ``chunk_tokens`` unless
@@ -679,7 +685,7 @@ class ContinuousEngine:
             self._advance_stream()
         if not self.live.any():
             return []
-        with span("serve.step"):
+        with span("serve.step") as sid:
             live_idx = np.flatnonzero(self.live)
             if self.paged:
                 # map this step's write page for every live slot from its
@@ -693,12 +699,18 @@ class ContinuousEngine:
                     if self._pool.dirty:
                         self._sync_page_table()
             with SH.use_mesh(self.mesh):
-                self.tok, self.pos, self.cache = self._step(
+                self.tok, self.pos, self.cache, *counts = self._step(
                     self.params, self.tok, self.pos, jnp.asarray(self.live),
                     self.cache, self.cushion_block)
             if self.paged:
                 self._hpos[live_idx] += 1   # mirror the device pos advance
-            toks = host_sync(self.tok)      # the one host sync per step
+            # the one host sync per step: the tokens, and the routing
+            # counters of an expert family in the same transfer
+            if counts:
+                toks, counts = host_sync((self.tok, counts))
+                self._count_routes("jit_step", sid, counts)
+            else:
+                toks = host_sync(self.tok)
             with span("serve.step.retire"):
                 self.stats.steps += 1
                 self.stats.live_slot_steps += int(self.live.sum())
@@ -766,7 +778,18 @@ class ContinuousEngine:
                 f"> pool max_seq {self.max_seq}")
         return need
 
-    def _admit_request(self, req: Request, slot: int) -> bool:
+    def _count_routes(self, program: str, span_id: int, counts) -> None:
+        """Add one call's routing counters to the totals and record them
+        under the call's span."""
+        rows, experts, dropped = (int(v) for v in counts[0])
+        self.stats.routed_rows += rows
+        self.stats.experts_reached += experts
+        self.stats.dropped_rows += dropped
+        count(f"moe.{program}", span_id, rows=rows, experts=experts,
+              dropped=dropped)
+
+    def _admit_request(self, req: Request, slot: int,
+                       span_id: Optional[int] = None) -> bool:
         """Blocking admission: B=1 prefill, then the full-row scatter into
         ``slot`` (dense pool) or the page scatter (paged pool). Paged, it
         first claims pages (full reservation — mid-decode exhaustion is
@@ -805,9 +828,10 @@ class ContinuousEngine:
                 b2["tokens"] = req.batch["tokens"][:, t_skip:]
                 logits, row, rpos = self._prefill_cu(self.params, b2, row,
                                                      cu2)
+                counts = []     # the stem prefill is not jit_prefill
             else:
-                logits, row, rpos = self._prefill(self.params, req.batch,
-                                                  row)
+                logits, row, rpos, *counts = self._prefill(
+                    self.params, req.batch, row)
             logits = logits[:, -1] if logits.ndim == 3 else logits
             tok0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)[0]
             sl = jnp.asarray(slot, jnp.int32)
@@ -818,7 +842,12 @@ class ContinuousEngine:
             else:
                 self.cache, self.pos, self.tok = self._admit(
                     self.cache, row, sl, self.pos, self.tok, rpos, tok0)
-        first = int(host_sync(tok0))
+        if counts:
+            first, counts = host_sync((tok0, counts))
+            self._count_routes("jit_prefill", span_id, counts)
+        else:
+            first = host_sync(tok0)
+        first = int(first)
         with span("serve.admit.book"):
             if tokens is not None:
                 self._pool.register_stem(slot, tokens, prefill_end)
@@ -908,7 +937,7 @@ class ContinuousEngine:
                         self.params, b0, st.row, self._stem_cushion(st.shared))
                 else:
                     st.logits, st.row, st.rpos = self._prefill(
-                        self.params, b0, st.row)
+                        self.params, b0, st.row)[:3]
             else:
                 st.logits, st.row, st.rpos = self._prefill_re(
                     self.params, {"tokens": chunk}, st.row,

@@ -11,7 +11,11 @@ and compile it, asserting the kernel reaches the program as a
 scatter compile at the same widths with two layers and 1057 pages, and
 must write the lane-dense page store in place: no copy, slice or
 slice-update of the store or of one layer of it, and temporaries under
-one layer's store. The topology is built inside module fixtures, never at
+one layer's store. At OLMoE-1B-7B widths (16 KV heads at head_dim 128, 64
+experts of width 1024, 833 pages) the int8 decode step and the admission
+must do the same, and no op may copy, slice or stack a whole layer's
+expert stack: the grouped kernel reads each layer's experts out of the
+stack by index. The topology is built inside module fixtures, never at
 import, so only the worker that runs this file loads the TPU compiler. The
 persistent compilation cache is off around the compiles: a TPU executable
 written here could not be read back without a chip.
@@ -26,10 +30,12 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import QuantConfig, get_config
+from repro.core import quantization as Q
 from repro.core.quantization import _tile
 from repro.kernels.flash_decode import (flash_decode, flash_decode_paged,
                                         page_rows)
 from repro.kernels.w4a8_matmul import w4a8_matmul
+from repro.kernels.w8a8_gmm import w8a8_gmm
 from repro.kernels.w8a8_matmul import w8a8_matmul
 from repro.models.registry import build
 from repro.serving.scheduler import scatter_pages
@@ -85,12 +91,16 @@ def _kv(dtype, paged=False):
     return [(shape, dtype), (shape, dtype)]
 
 
-def _store_moves(text):
+def _store_moves(text, store=STORE):
     """The compiled program's copies, slices and slice-updates whose result
     has the page store's shape or one layer's, with or without a unit
     layer axis: what writing in place rules out."""
-    shapes = {",".join(map(str, STORE)), ",".join(map(str, STORE[1:])),
-              ",".join(map(str, (1,) + STORE[1:]))}
+    shapes = {",".join(map(str, store)), ",".join(map(str, store[1:])),
+              ",".join(map(str, (1,) + store[1:]))}
+    return _moves(text, shapes)
+
+
+def _moves(text, shapes):
     pat = re.compile(r"= \w+\[([\d,]+)\]\S* "
                      r"(copy|dynamic-slice|dynamic-update-slice)\(")
     return [line.strip() for line in text.splitlines()
@@ -207,4 +217,87 @@ def test_w4a8_matmul(compile_tpu, M, K, N):
                                          bn=_tile(N, 512)),
         ((M, K), I8), ((K // 2, N), I8), ((K // GROUP, N), F32),
         ((N,), F32))
+    assert "tpu_custom_call" in text
+
+
+# OLMoE-1B-7B: 16/16 heads at head_dim 128 (one position per lane row), 64
+# experts of width 1024 at d=2048, the code cell's 16 slots over 833 pages
+OLMOE_STORE = (LAYERS, 833, PS // page_rows(128), 16, 128)
+OLMOE_LAYER_BYTES = 833 * PS * 16 * 128 * 2
+EXPERTS, D_MODEL, D_FF = 64, 2048, 1024
+
+
+def _expert_moves(text):
+    """Copies, slices and slice-updates of a whole layer's experts (int8 or
+    bf16, with or without a unit layer axis) or of the stack."""
+    shapes = set()
+    for k, n in ((D_MODEL, D_FF), (D_FF, D_MODEL)):
+        for lead in ((EXPERTS,), (1, EXPERTS), (LAYERS, EXPERTS)):
+            shapes.add(",".join(map(str, lead + (k, n))))
+    return _moves(text, shapes)
+
+
+def test_olmoe_decode_step_in_place(compile_tpu, monkeypatch):
+    """The W8A8 decode step at OLMoE widths (two layers, QK-norm, int8
+    experts, the routing counters): the store is written in place and no
+    op copies an expert stack."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), n_layers=LAYERS)
+    api = build(cfg)
+    qcfg = QuantConfig(mode="pt_static", true_int8=True)
+    params = jax.eval_shape(lambda: Q.prequantize_tree(
+        api.init_params(jax.random.PRNGKey(0)), qcfg))
+    scales = api.mod.placeholder_all_scales(cfg)
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    slots, pages = 16, 52
+    cache = {"k": OLMOE_STORE, "v": OLMOE_STORE,
+             "page_table": (LAYERS, slots, pages),
+             "kc": (LAYERS, 16, 16, 128), "vc": (LAYERS, 16, 16, 128)}
+    dtypes = {"page_table": I32}
+    names = list(cache)
+
+    def step(*args):
+        p = jax.tree_util.tree_unflatten(tree, args[:len(leaves)])
+        tok, pos = args[len(leaves):len(leaves) + 2]
+        c = dict(zip(names, args[len(leaves) + 2:]))
+        return api.decode_step(p, tok, pos, c, qcfg, scales=scales,
+                               route_counts=True)
+
+    donate = tuple(range(len(leaves) + 2, len(leaves) + 2 + len(names)))
+    compiled = compile_tpu(
+        step, *[(x.shape, x.dtype) for x in leaves], ((slots,), I32),
+        ((slots,), I32), *[(cache[n], dtypes.get(n, BF16)) for n in names],
+        donate=donate, compiled=True)
+    text = compiled.as_text()
+    assert "w8a8_gmm" in text and "flash_decode_paged" in text
+    assert _store_moves(text, OLMOE_STORE) == []
+    assert _expert_moves(text) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < OLMOE_LAYER_BYTES
+
+
+def test_olmoe_admit_paged_scatter_in_place(compile_tpu):
+    max_seq = 52 * PS
+    compiled = compile_tpu(
+        lambda k, v, rk, rv, idx: (scatter_pages(k, rk, idx),
+                                   scatter_pages(v, rv, idx)),
+        (OLMOE_STORE, BF16), (OLMOE_STORE, BF16),
+        ((LAYERS, 1, max_seq, 16, 128), BF16),
+        ((LAYERS, 1, max_seq, 16, 128), BF16), ((max_seq // PS,), I32),
+        donate=(0, 1), compiled=True)
+    assert _store_moves(compiled.as_text(), OLMOE_STORE) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < OLMOE_LAYER_BYTES
+
+
+@pytest.mark.parametrize("K,N", [(D_MODEL, D_FF), (D_FF, D_MODEL)])
+@pytest.mark.parametrize("M,tm", [(128, 32), (25856, 128)],
+                         ids=["decode", "prefill"])
+def test_w8a8_gmm(compile_tpu, M, tm, K, N):
+    """The grouped kernel over a stacked layer of experts, with the row
+    tiles the serving route picks for 16 slots x 8 experts and for a
+    3232-token prompt."""
+    text = compile_tpu(
+        lambda x, w, gs, sc, cs, ly: w8a8_gmm(x, w, gs, 0.02, 3.0, sc, cs,
+                                              ly, tm=tm, tn=_tile(N, 1024)),
+        ((M, K), I8), ((LAYERS, EXPERTS, K, N), I8), ((EXPERTS,), I32),
+        ((LAYERS, EXPERTS, N), F32), ((LAYERS, EXPERTS, N), I32), ((), I32))
     assert "tpu_custom_call" in text

@@ -7,9 +7,9 @@
 * the ``REPRO_W8A8_KERNEL`` routing flag: Pallas-forced (interpret-mode)
   execution of ``true_int_dot``/``prequantized_int_dot`` matches the
   lax.dot_general path, including under jit (the decode-scan context);
-* ``prequantize_tree`` converts exactly the qdot-consumed weights across
-  families (hybrid's list-nested period params included; MoE experts and
-  embeddings stay fp);
+* ``prequantize_tree`` converts exactly the qdot-consumed weights and the
+  MoE expert stacks across families (hybrid's list-nested period params
+  included; the router and embeddings stay fp);
 * the engines' load-time quantization plan: pt_static with neither scales
   nor calibration data refuses to run (the placeholder-scales silent-garbage
   guard), engine-side calibration equals precomputed-scales serving, and
@@ -115,8 +115,9 @@ def test_w8a8_kernel_routing_flag(monkeypatch, prequantized):
 
 def test_prequantize_tree_hybrid_descends_period_lists():
     """Hybrid period params nest sublayers in lists: attention / mamba /
-    dense-mlp weights inside them convert to int8-resident dicts; MoE
-    sublayers (expert einsums + Arctic residual) and embeddings stay fp."""
+    dense-mlp weights inside them convert to int8-resident dicts, MoE
+    sublayers' expert stacks to their grouped int8 form (the router stays
+    fp); embeddings stay fp."""
     cfg = reduced(get_config("jamba-v0.1-52b"), dtype="float32")
     api = build(cfg)
     p = api.init_params(jax.random.PRNGKey(0))
@@ -133,7 +134,11 @@ def test_prequantize_tree_hybrid_descends_period_lists():
     assert "w_int" in kinds["mamba"]["w_in"]
     assert not isinstance(kinds["mamba"]["w_x"], dict)   # raw einsum: fp
     assert "w_int" in kinds["mlp"]["w_down"]
-    assert not isinstance(kinds["moe"]["w_up"], dict)    # experts: fp
+    experts = kinds["moe"]["w_up"]                       # (P, E, D, F)
+    assert experts["w_int"].dtype == jnp.int8
+    assert experts["w_scale"].shape == experts["colsum"].shape \
+        == experts["w_int"].shape[:2] + experts["w_int"].shape[-1:]
+    assert not isinstance(kinds["moe"]["router"], dict)  # router: fp
     assert not isinstance(pq["embed"]["w"], dict)
     # stacked-over-periods leaves quantize per period slice
     P = kinds["attn"]["wqkv"]["w_int"].shape[0]
